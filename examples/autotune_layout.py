@@ -50,11 +50,8 @@ def main():
     cfg = get_config("qwen2-0.5b").reduced()
     # a small but real shape so compiles stay ~seconds
     shape = ShapeSpec("mini_train", seq_len=128, global_batch=8, kind="train")
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     evaluator = CostModelEvaluator(chips=8)
 
     def lower_variant(**knobs):

@@ -64,7 +64,14 @@ class DispatchSpec:
       *local* shard of those dims (global dim ÷ data-parallel degree), so
       campaign records tuned at local shard shapes exact-hit inside
       jit-sharded traces. Default ``(0,)`` (the row-major convention);
-      ``()`` disables localization for a kernel.
+      ``()`` disables localization for a kernel. The same dims are what
+      kernel-mode dispatch shards the kernel over (``jax.shard_map``), so
+      each device runs exactly the local shapes its key names.
+    * ``reduced_outputs`` — indices of the kernel's outputs (in its flat
+      output tuple) that sum over the sharded token dim, such as a weight
+      gradient: under a sharded mesh each device's partial result is summed
+      over the data axes. A call whose ``dp_dims`` moves the sharded dim
+      into the contraction reduces every output.
     * ``vjp`` — how dispatch differentiates the kernel path.
 
       * ``"dispatch"`` — the backward pass is itself a set of dispatch
@@ -116,6 +123,7 @@ class DispatchSpec:
     canonicalize: Optional[Callable[..., Tuple[tuple, Callable]]] = None
     example: Optional[Callable[[], Tuple[tuple, Dict[str, Any]]]] = None
     data_parallel_args: Tuple[int, ...] = (0,)
+    reduced_outputs: Tuple[int, ...] = ()
     vjp: str = "reference"
     bwd: Optional[Callable] = None
     residuals: int = 0

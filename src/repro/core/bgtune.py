@@ -163,6 +163,10 @@ class BackgroundTuner:
         while time.monotonic() < deadline:
             with self._lock:
                 idle = self._inflight == 0
+            # A worker that died (even before this call) left its job
+            # unfinished: that is never a drained queue.
+            if self._death is not None:
+                return False
             if idle:
                 return True
             if not self.accepting:

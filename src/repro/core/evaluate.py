@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .platform import HardwareProfile, TPU_V5E
+from .platform import HardwareProfile, TPU_V5E, detect_platform
 
 # ---------------------------------------------------------------------------
 # Correctness gate
@@ -104,16 +104,21 @@ class WallClockEvaluator(Evaluator):
     """Median-of-k wall time of the jitted variant (after compile + warmup).
 
     This is the paper's measurement, verbatim: each variant is compiled,
-    executed, timed, and its output compared to the reference output.
+    executed, timed, and its output compared to the reference output. A
+    variant that fails to compile or run, or fails the correctness gate,
+    scores ``inf`` and the search moves on — unless ``strict``, where it
+    raises: for checks in which any failing variant is a fault to report.
     """
 
     name = "wallclock"
 
-    def __init__(self, repeats: int = 5, warmup: int = 2, rtol=None, atol=None):
+    def __init__(self, repeats: int = 5, warmup: int = 2, rtol=None, atol=None,
+                 strict: bool = False):
         self.repeats = repeats
         self.warmup = warmup
         self.rtol = rtol
         self.atol = atol
+        self.strict = strict
 
     def evaluate(self, fn: Callable, args: Sequence[Any], reference=None) -> Measurement:
         try:
@@ -121,9 +126,13 @@ class WallClockEvaluator(Evaluator):
             out = jfn(*args)
             jax.block_until_ready(out)
         except Exception as e:  # invalid variant (bad tile, OOM, ...) — prune
+            if self.strict:
+                raise
             return Measurement(math.inf, False, error=f"{type(e).__name__}: {e}")
 
         if reference is not None and not correctness_gate(out, reference, self.rtol, self.atol):
+            if self.strict:
+                raise AssertionError(f"{fn}: correctness gate failed")
             return Measurement(math.inf, False, error="correctness gate failed")
 
         for _ in range(self.warmup):
@@ -333,11 +342,14 @@ class RooflineTerms:
 
 def roofline_from_compiled(
     compiled,
-    profile: HardwareProfile = TPU_V5E,
+    profile: Optional[HardwareProfile] = None,
     chips: Optional[int] = None,
     hlo_text: Optional[str] = None,
 ) -> RooflineTerms:
     """Derive the three roofline terms from a compiled executable.
+
+    ``profile`` defaults to the detected platform's peaks; a cost model of
+    another chip (a dry-run on host devices) passes that chip's profile.
 
     cost_analysis() reports whole-program FLOPs/bytes (already per the SPMD
     module, i.e. per device). Collective bytes come from the HLO text. The
@@ -345,10 +357,9 @@ def roofline_from_compiled(
     the busiest kind / link bandwidth.
     """
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns [dict]
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     bytes_accessed = float(ca.get("bytes accessed", ca.get("bytes_accessed", 0.0)))
+    profile = profile or detect_platform()
     n = chips or len(jax.devices())
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = collective_stats(text)

@@ -29,6 +29,11 @@ lse+dl passes, flash backward's three passes) return one model per
   (8 for f32, 16 for bf16). A block spanning the full dim is exempt (Mosaic
   pads whole arrays). Platform-dependent → these are *pruned configs*, not
   bugs.
+* **VMEM** — a model that states its scoped-VMEM need at these shapes
+  (``GridModel.vmem_need``, a function of the element size, for kernels
+  whose need depends on shapes a config does not name) must fit the
+  per-kernel limit at the dtype the kernel runs at. TPU-only → a pruned
+  config.
 
 ``config_verdict`` / ``space_illegal`` / ``space_report`` are the low-level
 API; ``ParamSpace.legal_configs(platform)`` (see ``params.py``) and the
@@ -42,7 +47,7 @@ import dataclasses
 import itertools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
-from .platform import PROFILES, HardwareProfile, detect_platform
+from .platform import PROFILES, SCOPED_VMEM_BYTES, HardwareProfile, detect_platform
 
 # ---------------------------------------------------------------------------
 # Model structures
@@ -83,6 +88,8 @@ class GridModel:
     grid: Tuple[int, ...]
     semantics: Tuple[str, ...]       # "parallel" | "arbitrary" per axis
     refs: Tuple[RefModel, ...]
+    # Scoped VMEM need in bytes given the element size, where shape-bound.
+    vmem_need: Optional[Callable[[int], int]] = None
 
     def __post_init__(self):
         if len(self.grid) != len(self.semantics):
@@ -260,9 +267,8 @@ def check_alignment(
     alignment — a block spanning the full dim is laid out by Mosaic's
     whole-array padding and is always representable. For tiled axes, the
     last block dim must divide by the lane count and the second-to-last by
-    the per-dtype sublane count; a second-to-minor extent of exactly 1 is
-    a single sublane row and is also representable (the (1, block_q) lse
-    row blocks of flash attention backward).
+    the per-dtype sublane count — no exemption for an extent of 1: Mosaic
+    refuses a (1, block_q) block that tiles its axis.
     """
     if not profile.name.startswith("tpu"):
         return None
@@ -276,7 +282,7 @@ def check_alignment(
                 f"{blk[-1]} tiles axis of {dims[-1]} but is not a multiple "
                 f"of {profile.lanes} lanes ({profile.name})"
             )
-        if len(blk) >= 2 and 1 < blk[-2] < dims[-2] and blk[-2] % sub:
+        if len(blk) >= 2 and blk[-2] < dims[-2] and blk[-2] % sub:
             return (
                 f"{model.kernel}: ref {ref.name!r} second-to-last block dim "
                 f"{blk[-2]} tiles axis of {dims[-2]} but is not a multiple "
@@ -285,12 +291,28 @@ def check_alignment(
     return None
 
 
+def check_vmem(
+    model: GridModel, profile: HardwareProfile, dtype: str = "float32"
+) -> Optional[str]:
+    """A stated scoped-VMEM need at ``dtype`` over the per-kernel limit
+    (skipped off-TPU and for models that state none)."""
+    if not profile.name.startswith("tpu") or model.vmem_need is None:
+        return None
+    need = model.vmem_need(_DTYPE_BYTES.get(str(dtype), 4))
+    if need > SCOPED_VMEM_BYTES:
+        return (
+            f"{model.kernel}: needs {need} B of scoped VMEM at {dtype}, "
+            f"over the {SCOPED_VMEM_BYTES} B limit ({profile.name})"
+        )
+    return None
+
+
 def check_model(
     model: GridModel, profile: HardwareProfile, dtype: str = "float32"
 ) -> Optional[Tuple[str, str]]:
     """(category, reason) for the first failed check, in severity order:
-    races and OOB are kernel bugs regardless of platform; alignment is a
-    platform-specific infeasibility (a pruned config, not a bug)."""
+    races and OOB are kernel bugs regardless of platform; alignment and
+    VMEM are platform-specific infeasibilities (pruned configs, not bugs)."""
     reason = check_races(model)
     if reason:
         return ("race", reason)
@@ -300,6 +322,9 @@ def check_model(
     reason = check_alignment(model, profile, dtype)
     if reason:
         return ("align", reason)
+    reason = check_vmem(model, profile, dtype)
+    if reason:
+        return ("vmem", reason)
     return None
 
 
@@ -323,9 +348,12 @@ def config_verdict(
     config: Dict[str, Any],
     platform: Union[str, HardwareProfile, None] = None,
     shapes: Optional[Sequence[Tuple[int, ...]]] = None,
+    dtype: Optional[str] = None,
 ) -> Optional[Tuple[str, str]]:
     """None if the config is legal for ``kernel`` on ``platform``, else the
-    first (category, reason): 'build' | 'race' | 'oob' | 'align'."""
+    first (category, reason): 'build' | 'race' | 'oob' | 'align' | 'vmem'.
+    ``dtype`` is the element type the kernel runs at (default: the one its
+    nominal shapes were registered with)."""
     builder = _GRID_MODELS.get(kernel)
     if builder is None:
         return None                  # no model declared → nothing to check
@@ -337,7 +365,7 @@ def config_verdict(
             f"{kernel}: kernel rejects config {config} at these shapes",
         )
     for m in models:
-        verdict = check_model(m, profile, builder.dtype)
+        verdict = check_model(m, profile, dtype or builder.dtype)
         if verdict:
             return verdict
     return None
@@ -347,6 +375,7 @@ def space_illegal(
     kernel: str,
     platform: Union[str, HardwareProfile, None] = None,
     shapes: Optional[Sequence[Tuple[int, ...]]] = None,
+    dtype: Optional[str] = None,
 ) -> Dict[str, Tuple[str, str]]:
     """config_key → (category, reason) over the kernel's whole space."""
     builder = _GRID_MODELS.get(kernel)
@@ -355,7 +384,7 @@ def space_illegal(
     profile = resolve_profile(platform)
     out: Dict[str, Tuple[str, str]] = {}
     for cfg in builder.space.enumerate():
-        verdict = config_verdict(kernel, cfg, profile, shapes)
+        verdict = config_verdict(kernel, cfg, profile, shapes, dtype)
         if verdict:
             out[builder.space.config_key(cfg)] = verdict
     return out

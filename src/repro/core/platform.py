@@ -21,8 +21,12 @@ An override naming a known profile selects it; an unknown name clones the
 fingerprinted profile under the new name, so roofline peaks stay sensible
 while the database namespace is fully isolated.
 
-Constants for TPU v5e follow the brief: 197 TFLOP/s bf16 per chip,
-819 GB/s HBM, ~50 GB/s/link ICI, 16 GiB HBM, 128 MiB VMEM.
+Peaks come from Google Cloud's TPU documentation ("TPU v5e": 197 TFLOP/s
+bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip; "TPU v4": 275
+TFLOP/s bf16, 32 GiB HBM at 1,200 GB/s). Fingerprinting maps
+``device_kind`` (what ``jax.devices()[0].device_kind`` reports) onto a
+profile through :data:`DEVICE_KINDS`; a TPU kind missing from that table is
+an error, never a guess.
 """
 from __future__ import annotations
 
@@ -32,6 +36,11 @@ from typing import Optional, Union
 
 import jax
 
+# Scoped VMEM one Pallas kernel is compiled under (the Mosaic default on
+# v4/v5e; every kernel passes it explicitly). Tile working-set budgets and
+# the legality pass check against this, not against the VMEM capacity.
+SCOPED_VMEM_BYTES = 16 * 1024**2
+
 
 @dataclasses.dataclass(frozen=True)
 class HardwareProfile:
@@ -40,8 +49,8 @@ class HardwareProfile:
     hbm_bandwidth: float           # bytes/s per chip
     ici_bandwidth: float           # bytes/s per link
     hbm_bytes: int                 # per-chip HBM capacity
-    vmem_bytes: int                # per-core VMEM (tile working-set budget)
-    mxu_dim: int = 128             # systolic array native tile edge
+    vmem_bytes: int                # per-core VMEM capacity
+    mxu_dim: int = 128            # systolic array native tile edge
     lanes: int = 128               # VPU lane count (last-dim alignment)
     sublanes: int = 8              # second-to-last-dim alignment (fp32)
 
@@ -50,7 +59,7 @@ TPU_V5E = HardwareProfile(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
     hbm_bandwidth=819e9,
-    ici_bandwidth=50e9,
+    ici_bandwidth=50e9,            # 1,600 Gbit/s per chip over 4 links
     hbm_bytes=16 * 1024**3,
     vmem_bytes=128 * 1024**2,
 )
@@ -58,7 +67,7 @@ TPU_V5E = HardwareProfile(
 TPU_V4 = HardwareProfile(
     name="tpu-v4",
     peak_flops_bf16=275e12,
-    hbm_bandwidth=1228e9,
+    hbm_bandwidth=1200e9,
     ici_bandwidth=100e9,
     hbm_bytes=32 * 1024**3,
     vmem_bytes=128 * 1024**2,
@@ -77,6 +86,10 @@ CPU_HOST = HardwareProfile(
 )
 
 PROFILES = {p.name: p for p in (TPU_V5E, TPU_V4, CPU_HOST)}
+
+# jax ``device_kind`` -> profile. The source of each row's peaks is in the
+# module docstring.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E, "TPU v4": TPU_V4}
 
 # Process-wide explicit override (set_platform_override / REPRO_PLATFORM).
 _override: Optional[str] = None
@@ -102,17 +115,23 @@ def platform_override() -> Optional[str]:
 def _fingerprint() -> HardwareProfile:
     """Map ``jax.devices()`` onto a known profile.
 
-    On a real pod ``jax.devices()[0].platform == 'tpu'`` and ``device_kind``
-    distinguishes generations (e.g. "TPU v4", "TPU v5 lite"); in this
-    container it is 'cpu'. Tuning records are stored under the detected key,
-    so a database produced here never shadows a TPU database — that
-    isolation is what makes shipping per-platform DBs safe.
+    On a TPU ``device_kind`` names the generation ("TPU v4", "TPU v5 lite");
+    on a CPU host the platform is 'cpu'. Tuning records are stored under the
+    detected key, so a database produced on one never shadows another's —
+    that isolation is what makes shipping per-platform DBs safe. A device
+    without a row in :data:`DEVICE_KINDS` raises: roofline shares and VMEM
+    budgets computed from another chip's peaks would be wrong silently.
     """
     dev = jax.devices()[0]
-    if dev.platform == "tpu":
-        kind = getattr(dev, "device_kind", "").lower()
-        return TPU_V4 if "v4" in kind else TPU_V5E
-    return CPU_HOST
+    if dev.platform == "cpu":
+        return CPU_HOST
+    prof = DEVICE_KINDS.get(dev.device_kind)
+    if prof is None:
+        raise ValueError(
+            f"no hardware profile for {dev.platform} device_kind "
+            f"{dev.device_kind!r}; known kinds: {sorted(DEVICE_KINDS)}"
+        )
+    return prof
 
 
 def detect_platform(override: Optional[str] = None) -> HardwareProfile:

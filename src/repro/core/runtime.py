@@ -79,6 +79,7 @@ import collections
 import contextlib
 import contextvars
 import dataclasses
+import functools
 import os
 import threading
 import time
@@ -117,6 +118,19 @@ def _platform() -> str:
     if _platform_name is None:
         _platform_name = detect_platform().name
     return _platform_name
+
+_backend_tpu: Optional[bool] = None
+
+
+def _on_tpu() -> bool:
+    """Whether JAX's default backend is a TPU (memoized like the platform
+    key: the backend cannot change within a process)."""
+    global _backend_tpu
+    if _backend_tpu is None:
+        import jax
+
+        _backend_tpu = jax.default_backend() == "tpu"
+    return _backend_tpu
 
 # Resolution tiers, in the order the default pipeline consults them.
 # "bgtune" is the BackgroundTune tier (repro.core.bgtune): a miss served by
@@ -651,16 +665,15 @@ class TunedRuntime:
     def kernel_mode_active(self) -> bool:
         """Whether dispatch takes the kernel path (vs reference).
 
-        ``"auto"`` reads ``REPRO_USE_PALLAS`` lazily, so flipping the env var
-        between calls behaves the same as the old import-time ``_STATE``
-        for test processes that set it up front, while also supporting
-        per-leg CI overrides.
+        ``"auto"`` takes it on a TPU, where the kernels compile natively.
+        Elsewhere they only run interpreted, so ``"auto"`` reads
+        ``REPRO_USE_PALLAS`` lazily (CI legs opt in with ``=1``).
         """
         if self.mode == "kernel":
             return True
         if self.mode == "reference":
             return False
-        return os.environ.get("REPRO_USE_PALLAS", "0") == "1"
+        return _on_tpu() or os.environ.get("REPRO_USE_PALLAS", "0") == "1"
 
     # -- cache ---------------------------------------------------------------
     def clear_cache(self) -> None:
@@ -864,7 +877,8 @@ class TunedRuntime:
             # by hand (tests, benchmarks) and wants the real traceback.
             self.telemetry.record(tunable.name, None, "override")
             cargs, restore = spec.canon(args)
-            return restore(_kernel_call(self, tunable, spec, config, cargs, kwargs))
+            return restore(_kernel_call(self, tunable, spec, config, cargs, kwargs,
+                                        dp_dims))
         cargs, restore = spec.canon(args)
         res = self.resolve(tunable, cargs, key_extra=spec.extra_for(kwargs),
                            dp_dims=dp_dims)
@@ -872,10 +886,13 @@ class TunedRuntime:
             return _reference_call(tunable, spec, args, kwargs)
         if not self.guard:
             _fault_point(f"dispatch.kernel:{tunable.name}", tier=res.tier)
-            return restore(_kernel_call(self, tunable, spec, res.config, cargs, kwargs))
-        return self._guarded_call(tunable, spec, res, args, cargs, restore, kwargs)
+            return restore(_kernel_call(self, tunable, spec, res.config, cargs,
+                                        kwargs, dp_dims))
+        return self._guarded_call(tunable, spec, res, args, cargs, restore, kwargs,
+                                  dp_dims)
 
-    def _guarded_call(self, tunable, spec, res, args, cargs, restore, kwargs):
+    def _guarded_call(self, tunable, spec, res, args, cargs, restore, kwargs,
+                      dp_dims):
         """Execute a resolved kernel variant behind the fault guard.
 
         On exception (or a failed non-finite probe) the bucket's db key is
@@ -893,7 +910,8 @@ class TunedRuntime:
         key = res.key
         try:
             rule = _fault_point(f"dispatch.kernel:{tunable.name}", tier=res.tier)
-            out = _kernel_call(self, tunable, spec, res.config, cargs, kwargs)
+            out = _kernel_call(self, tunable, spec, res.config, cargs, kwargs,
+                               dp_dims)
             if rule is not None and rule.kind == "nan":
                 out = _nan_corrupt(out)
             if res.probe:
@@ -914,7 +932,8 @@ class TunedRuntime:
             if hcfg != res.config:
                 try:
                     _fault_point(f"dispatch.kernel:{tunable.name}", tier="heuristic")
-                    out = _kernel_call(self, tunable, spec, hcfg, cargs, kwargs)
+                    out = _kernel_call(self, tunable, spec, hcfg, cargs, kwargs,
+                                       dp_dims)
                     self.telemetry.record(tunable.name, key, "heuristic")
                     return restore(out)
                 except Exception as e2:
@@ -985,8 +1004,12 @@ _DEFAULT_SPEC = DispatchSpec()
 
 
 def _kernel_call(runtime: "TunedRuntime", tunable: Tunable, spec: DispatchSpec,
-                 config: Config, cargs: tuple, kwargs: Dict[str, Any]):
+                 config: Config, cargs: tuple, kwargs: Dict[str, Any],
+                 dp_dims: Optional[Dict[int, int]] = None):
     """Execute one bound kernel variant on canonical args, trainably.
+
+    Under a mesh that splits the batch, the variant runs per device
+    (:func:`_per_device`): a Mosaic kernel cannot be partitioned by XLA.
 
     Pallas kernels have no transpose rules, so a bare variant inside
     ``jax.grad`` fails. Three backward strategies, per ``spec.vjp``:
@@ -1014,25 +1037,26 @@ def _kernel_call(runtime: "TunedRuntime", tunable: Tunable, spec: DispatchSpec,
     """
     import jax
 
-    variant = tunable.variant(**config)
+    variant = _per_device(functools.partial(tunable.variant(**config), **kwargs),
+                          spec, cargs, dp_dims)
     ref = spec.reference_for(tunable)
     n_res = spec.residuals
     mode = spec.vjp
     if mode == "dispatch" and (spec.bwd is None or not runtime.bwd_dispatch):
         mode = "reference"
     if mode == "none" or (mode == "reference" and ref is None):
-        out = variant(*cargs, **kwargs)
+        out = variant(*cargs)
         return out[0] if n_res else out
 
     # kwargs (eps/causal/window/...) are schedule-or-semantics flags, never
-    # differentiated: bind them by closure so custom_vjp sees arrays only.
+    # differentiated: bound by closure above, so custom_vjp sees arrays only.
     @jax.custom_vjp
     def run(*a):
-        out = variant(*a, **kwargs)
+        out = variant(*a)
         return out[0] if n_res else out
 
     def fwd(*a):
-        out = variant(*a, **kwargs)
+        out = variant(*a)
         if n_res:
             return out[0], (a, out[0], tuple(out[1:]))
         return out, (a, None, ())
@@ -1063,6 +1087,68 @@ def _kernel_call(runtime: "TunedRuntime", tunable: Tunable, spec: DispatchSpec,
         # still applies to our return value.
         runtime.telemetry.record(tunable.name, None, "reference")
         return ref(*cargs, **kwargs)
+
+
+def _per_device(fn: Callable, spec: DispatchSpec, cargs: tuple,
+                dp_dims: Optional[Dict[int, int]]) -> Callable:
+    """``fn`` run on each device's shard of the batch, via ``jax.shard_map``.
+
+    Only inside a mesh context that splits the batch (the trainer's scope,
+    ``dp_degree > 1``); elsewhere ``fn`` itself. The sharded dims are the
+    ones the database key localizes (``spec.data_parallel_args``, or this
+    call's ``dp_dims``), so each device runs exactly the shapes its key
+    names; every other arg is replicated. Outputs stay sharded on their
+    leading dim (replicated when no arg is split), except those that sum
+    over the sharded dim
+    (``spec.reduced_outputs``, or all of them when ``dp_dims`` moves the
+    sharded dim into the contraction), which are summed over the data axes.
+    A batch the degree does not divide is left unsplit, as its key is.
+    """
+    import jax
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+
+    from ..distributed import sharding as shd
+
+    ctx, dp = shd.current_mesh_layout(), shd.current_dp_degree()
+    if ctx is None or not dp or dp <= 1:
+        return fn
+    mesh, layout = ctx
+    axes = shd.data_axes_for(mesh, layout, dp)
+    if not axes:
+        return fn
+    ax = axes if len(axes) > 1 else axes[0]
+    dims = (dp_dims if dp_dims is not None
+            else {i: 0 for i in spec.data_parallel_args})
+    in_specs = []
+    for i, a in enumerate(cargs):
+        d, shape = dims.get(i), np.shape(a)
+        if d is None or len(shape) <= d:
+            in_specs.append(P())
+        elif shape[d] % dp:
+            return fn
+        else:
+            in_specs.append(P(*([None] * d), ax))
+    out_tree = jax.tree_util.tree_structure(jax.eval_shape(fn, *cargs))
+    # Nothing split (a kernel that localizes no arg): every device computes
+    # the whole result, which is then replicated, not sharded.
+    split = any(s != P() for s in in_specs)
+    reduced = set(range(out_tree.num_leaves) if dp_dims is not None or not split
+                  else spec.reduced_outputs)
+    out_specs = jax.tree_util.tree_unflatten(
+        out_tree, [P() if i in reduced else P(ax) for i in range(out_tree.num_leaves)]
+    )
+
+    def local(*a):
+        leaves = jax.tree_util.tree_leaves(fn(*a))
+        return jax.tree_util.tree_unflatten(out_tree, [
+            jax.lax.psum(x, ax) if split and i in reduced else x
+            for i, x in enumerate(leaves)
+        ])
+
+    # check_vma off: a pallas_call's out_shape states no per-axis variance.
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)
 
 
 def _jvp_nesting(args) -> int:

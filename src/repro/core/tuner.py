@@ -185,11 +185,12 @@ def autotune(
     try:
         from .gridmodel import space_illegal
 
-        shapes = tuple(
-            tuple(a.shape) for a in args if hasattr(a, "shape")
-        )
+        arrays = [a for a in args if hasattr(a, "shape")]
+        shapes = tuple(tuple(a.shape) for a in arrays)
+        # Tiles are laid out, and VMEM is spent, at the leading operand's type.
+        dtype = str(arrays[0].dtype) if arrays else None
         for ck, (cat, reason) in space_illegal(
-            tunable.name, platform, shapes or None
+            tunable.name, platform, shapes or None, dtype
         ).items():
             illegal[ck] = f"{cat}: {reason}"
     except Exception:                                 # pragma: no cover

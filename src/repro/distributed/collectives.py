@@ -104,8 +104,6 @@ def ring_all_reduce(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
     n = mesh.shape[axis]
     if n == 1:
         return x
-    from jax.experimental.shard_map import shard_map
-
     d = x.shape[-1]
     pad = (-d) % n
 
@@ -134,6 +132,6 @@ def ring_all_reduce(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
         return acc.reshape(1, -1)
 
     xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
-    fn = shard_map(body, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None))
     out = fn(xp)
     return out[:, :d] if pad else out

@@ -239,6 +239,18 @@ def localize_shapes(
     return tuple(one(i, s) for i, s in enumerate(shapes))
 
 
+def data_axes_for(mesh: Mesh, layout: Layout, degree: int) -> Tuple[str, ...]:
+    """The mesh axes a batch dim split ``degree`` ways is sharded over —
+    the greedy prefix of the data axes that :func:`batch_spec` takes."""
+    sizes = mesh_axis_sizes(mesh)
+    use, prod = [], 1
+    for a in dict.fromkeys(("pod",) + tuple(layout.data_axes)):
+        if a in sizes and degree % (prod * sizes[a]) == 0:
+            use.append(a)
+            prod *= sizes[a]
+    return tuple(use) if prod == degree else ()
+
+
 def batch_spec(mesh: Mesh, layout: Layout, batch_size: int) -> P:
     """Shard the batch dim over every data-ish axis that divides it."""
     use, _ = _divisible_data_axes(mesh_axis_sizes(mesh), layout, batch_size)

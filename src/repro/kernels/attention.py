@@ -22,9 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
 from ..core import Constraint, DispatchSpec, ParamSpace, PowerOfTwoParam, tunable
-from ..core.platform import TPU_V5E
+from ..core.platform import SCOPED_VMEM_BYTES
 from . import ref
 
 _NEG_INF = -1e30  # avoid nan from (-inf) - (-inf) in fully-masked rows
@@ -100,7 +99,7 @@ def _flash_kernel(
     def _done():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(denom))[:, 0]
+        lse_ref[0] = m_scr[...] + jnp.log(denom)
 
 
 def flash_attention_pallas(
@@ -158,18 +157,19 @@ def flash_attention_pallas(
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, s_q), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -179,14 +179,19 @@ def flash_attention_pallas(
     return out.reshape(b, h, s_q, d)
 
 
-def _attn_vmem_bytes(cfg, d: int = 128, dtype_bytes: int = 2) -> int:
+def _attn_vmem_bytes(cfg, d: int = 128, dtype_bytes: int = 4) -> int:
+    """Scoped VMEM of the largest of the three passes. Each double-buffers
+    its q/do/o and k/v tiles and holds f32 [bq, bk] score temporaries (two
+    forward, four backward); [bq] columns (lse, delta, m, l) pad to 128 f32
+    lanes, [bq] rows to 8 sublanes. d=128 and f32 operands bound every head
+    width and dtype the models use."""
     bq, bk = cfg["block_q"], cfg["block_k"]
-    return (
-        bq * d * dtype_bytes            # q tile
-        + 2 * bk * d * dtype_bytes      # k, v tiles
-        + bq * bk * 4                   # scores
-        + bq * (d + 2) * 4              # acc + m + l scratch
-    )
+    tiles = 2 * (2 * bq + 2 * bk) * d * dtype_bytes
+    col = bq * 128 * 4
+    fwd = tiles + 4 * col + bq * d * 4 + 2 * bq * bk * 4
+    dq = tiles + 4 * col + 2 * bq * d * dtype_bytes + bq * d * 4 + 4 * bq * bk * 4
+    dkv = tiles + 4 * 8 * bq * 4 + 6 * bk * d * 4 + 4 * bq * bk * 4
+    return max(fwd, dq, dkv)
 
 
 ATTENTION_SPACE = ParamSpace(
@@ -196,7 +201,7 @@ ATTENTION_SPACE = ParamSpace(
     ],
     [
         Constraint(
-            lambda c: _attn_vmem_bytes(c) <= TPU_V5E.vmem_bytes // 2,
+            lambda c: _attn_vmem_bytes(c) <= SCOPED_VMEM_BYTES,
             "attention tile working set exceeds VMEM budget",
         )
     ],
@@ -205,7 +210,6 @@ ATTENTION_SPACE = ParamSpace(
 
 def _attn_heuristic(q, k, v):
     s_q, s_k = q.shape[2], k.shape[2]
-    blk = lambda s: min(512, max(128, 1 << (int(s) - 1).bit_length() if s < 128 else 128))
     return {"block_q": min(512, max(128, min(s_q, 512))) if s_q >= 128 else 128,
             "block_k": 512 if s_k >= 512 else 128}
 
@@ -313,11 +317,11 @@ def _flash_bwd_dq_kernel(
             if window > 0:
                 mask &= (q_ids - k_ids) < window
             s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None])       # exact softmax via lse
+        p = jnp.exp(s - lse_ref[0])                # exact softmax via lse
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )                                           # [bq, bk]
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0])
         dq_scr[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -375,14 +379,14 @@ def _flash_bwd_dkv_kernel(
             if window > 0:
                 mask &= (q_ids - k_ids) < window
             st = jnp.where(mask, st, _NEG_INF)
-        pt = jnp.exp(st - lse_ref[0][None, :])     # [bk, bq]
+        pt = jnp.exp(st - lse_ref[0])              # [bk, bq]
         dv_scr[...] += jax.lax.dot_general(
             pt, do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         dpt = jax.lax.dot_general(
             v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )                                           # [bk, bq]
-        dst = pt * (dpt - delta_ref[0][None, :])
+        dst = pt * (dpt - delta_ref[0])
         dk_scr[...] += jax.lax.dot_general(
             dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -447,10 +451,15 @@ def flash_attention_bwd_pallas(
         block_q=block_q, block_k=block_k, q_offset=q_offset,
     )
 
-    # delta = rowsum(do·o) from the residual-threaded forward output
+    # delta = rowsum(do·o) from the residual-threaded forward output. The
+    # dq pass reads lse/delta as [bq, 1] columns (rows of its score block),
+    # the dk/dv pass as [1, bq] rows (columns of its transposed block): each
+    # layout keeps its block's last two dims tileable.
     o_r = o.reshape(b * h, s_q, d)
     lse_r = lse.astype(jnp.float32).reshape(b * h, s_q)
     delta = jnp.sum(dor.astype(jnp.float32) * o_r.astype(jnp.float32), axis=-1)
+    lse_col, delta_col = lse_r[:, :, None], delta[:, :, None]
+    lse_row, delta_row = lse_r[:, None, :], delta[:, None, :]
 
     # 1. dq: stream K/V blocks per Q block (k grid dim carries the acc)
     dq = pl.pallas_call(
@@ -461,17 +470,18 @@ def flash_attention_bwd_pallas(
             pl.BlockSpec((1, block_k, d), kv_index_q),
             pl.BlockSpec((1, block_k, d), kv_index_q),
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, ki: (bh, qi)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qr, kr, vr, dor, lse_r, delta)
+    )(qr, kr, vr, dor, lse_col, delta_col)
 
     # 2. dk/dv: stream Q blocks per K block (q grid dim carries the accs),
     # per q-head; group-sum into kv heads below.
@@ -483,8 +493,8 @@ def flash_attention_bwd_pallas(
             pl.BlockSpec((1, block_k, d), kv_index_k),
             pl.BlockSpec((1, block_k, d), kv_index_k),
             pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, ki, qi: (bh, qi)),
-            pl.BlockSpec((1, block_q), lambda bh, ki, qi: (bh, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, ki, qi: (bh, 0, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, ki, qi: (bh, 0, qi)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
@@ -498,11 +508,12 @@ def flash_attention_bwd_pallas(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qr, kr, vr, dor, lse_r, delta)
+    )(qr, kr, vr, dor, lse_row, delta_row)
     dk = dk_h.reshape(b, kv, group, s_k, d).sum(axis=2).astype(k.dtype)
     dv = dv_h.reshape(b, kv, group, s_k, d).sum(axis=2).astype(v.dtype)
     return dq.reshape(b, h, s_q, d), dk, dv
@@ -574,7 +585,6 @@ def _flash_grid_model(config, shapes=None):
         return None
     grid = (b * h, s_q // bq, s_k // bk)
     qmap = lambda bh, qi, ki: (bh, qi, 0)
-    lmap = lambda bh, qi, ki: (bh, qi)
     kvmap = lambda bh, qi, ki: ((bh // h) * kv + (bh % h) // group, ki, 0)
     return GridModel(
         "flash_attention", grid, ("parallel", "parallel", "arbitrary"),
@@ -583,7 +593,7 @@ def _flash_grid_model(config, shapes=None):
             RefModel("k", (1, bk, d), kvmap, (b * kv, s_k, d)),
             RefModel("v", (1, bk, d), kvmap, (b * kv, s_k, d)),
             RefModel("out", (1, bq, d), qmap, (b * h, s_q, d), role="out"),
-            RefModel("lse", (1, bq), lmap, (b * h, s_q), role="out"),
+            RefModel("lse", (1, bq, 1), qmap, (b * h, s_q, 1), role="out"),
         ),
     )
 
@@ -604,7 +614,6 @@ def _flash_bwd_grid_model(config, shapes=None):
         return None
     q_steps, k_steps = s_q // bq, s_k // bk
     qmap = lambda bh, qi, ki: (bh, qi, 0)
-    lmap = lambda bh, qi, ki: (bh, qi)
     kvmap = lambda bh, qi, ki: ((bh // h) * kv + (bh % h) // group, ki, 0)
     q_dims, kv_dims = (b * h, s_q, d), (b * kv, s_k, d)
     dq_pass = GridModel(
@@ -615,14 +624,14 @@ def _flash_bwd_grid_model(config, shapes=None):
             RefModel("k", (1, bk, d), kvmap, kv_dims),
             RefModel("v", (1, bk, d), kvmap, kv_dims),
             RefModel("do", (1, bq, d), qmap, q_dims),
-            RefModel("lse", (1, bq), lmap, (b * h, s_q)),
-            RefModel("delta", (1, bq), lmap, (b * h, s_q)),
+            RefModel("lse", (1, bq, 1), qmap, (b * h, s_q, 1)),
+            RefModel("delta", (1, bq, 1), qmap, (b * h, s_q, 1)),
             RefModel("dq", (1, bq, d), qmap, q_dims, role="out"),
         ),
     )
     # dk/dv stream Q per K block: grid axes are (bh, ki, qi).
     qmap_k = lambda bh, ki, qi: (bh, qi, 0)
-    lmap_k = lambda bh, ki, qi: (bh, qi)
+    lmap_k = lambda bh, ki, qi: (bh, 0, qi)
     kvmap_k = lambda bh, ki, qi: ((bh // h) * kv + (bh % h) // group, ki, 0)
     dkv_map = lambda bh, ki, qi: (bh, ki, 0)
     dkv_pass = GridModel(
@@ -633,8 +642,8 @@ def _flash_bwd_grid_model(config, shapes=None):
             RefModel("k", (1, bk, d), kvmap_k, kv_dims),
             RefModel("v", (1, bk, d), kvmap_k, kv_dims),
             RefModel("do", (1, bq, d), qmap_k, q_dims),
-            RefModel("lse", (1, bq), lmap_k, (b * h, s_q)),
-            RefModel("delta", (1, bq), lmap_k, (b * h, s_q)),
+            RefModel("lse", (1, 1, bq), lmap_k, (b * h, 1, s_q)),
+            RefModel("delta", (1, 1, bq), lmap_k, (b * h, 1, s_q)),
             RefModel("dk", (1, bk, d), dkv_map, (b * h, s_k, d), role="out"),
             RefModel("dv", (1, bk, d), dkv_map, (b * h, s_k, d), role="out"),
         ),

@@ -32,9 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
 from ..core import Constraint, DispatchSpec, ParamSpace, PowerOfTwoParam, tunable
-from ..core.platform import TPU_V5E
+from ..core.platform import SCOPED_VMEM_BYTES
 from . import ref
 from .matmul import _pad_to
 
@@ -106,7 +105,8 @@ def matmul_bias_act_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -114,12 +114,13 @@ def matmul_bias_act_pallas(
     return out[:m, :n]
 
 
-def _mba_vmem_bytes(cfg, dtype_bytes: int = 2) -> int:
+def _mba_vmem_bytes(cfg, dtype_bytes: int = 4) -> int:
+    """Double-buffered x, w, bias-row and out tiles plus the f32
+    accumulator, at f32 operands (as ``matmul``'s model)."""
     bm, bn, bk = cfg["bm"], cfg["bn"], cfg["bk"]
     return (
-        bm * bk * dtype_bytes + bk * bn * dtype_bytes
-        + bn * dtype_bytes                     # bias row
-        + bm * bn * (dtype_bytes + 4)          # out tile + fp32 acc
+        2 * (bm * bk + bk * bn + 8 * bn + bm * bn) * dtype_bytes
+        + bm * bn * 4
     )
 
 
@@ -131,7 +132,7 @@ FUSED_MATMUL_SPACE = ParamSpace(
     ],
     [
         Constraint(
-            lambda c: _mba_vmem_bytes(c) <= TPU_V5E.vmem_bytes // 2,
+            lambda c: _mba_vmem_bytes(c) <= SCOPED_VMEM_BYTES,
             "fused gemm tile working set exceeds VMEM budget",
         )
     ],
@@ -263,7 +264,8 @@ def rmsnorm_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -272,12 +274,12 @@ def rmsnorm_matmul_pallas(
 
 
 def _rmm_vmem_bytes(cfg, d: int = 4096, dtype_bytes: int = 2) -> int:
+    """Double-buffered x, scale-row, w and out tiles, plus the f32
+    normalized copy of x and the f32 product."""
     bm, bn = cfg["bm"], cfg["bn"]
     return (
-        bm * d * (dtype_bytes + 4)    # x tile + fp32 normalized copy
-        + d * dtype_bytes             # scale row
-        + d * bn * dtype_bytes        # w tile
-        + bm * bn * (dtype_bytes + 4)  # out tile + fp32 product
+        2 * (bm * d + 8 * d + d * bn + bm * bn) * dtype_bytes
+        + bm * d * 4 + bm * bn * 4
     )
 
 
@@ -288,7 +290,7 @@ RMSNORM_MATMUL_SPACE = ParamSpace(
     ],
     [
         Constraint(
-            lambda c: _rmm_vmem_bytes(c) <= TPU_V5E.vmem_bytes // 2,
+            lambda c: _rmm_vmem_bytes(c) <= SCOPED_VMEM_BYTES,
             "fused norm+gemm tile working set exceeds VMEM budget",
         )
     ],

@@ -16,9 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
 from ..core import Constraint, DispatchSpec, ParamSpace, PowerOfTwoParam, tunable
-from ..core.platform import TPU_V5E
+from ..core.platform import SCOPED_VMEM_BYTES
 from . import ref
 
 
@@ -78,7 +77,8 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -86,9 +86,12 @@ def matmul_pallas(
     return out[:m, :n]
 
 
-def _vmem_bytes(cfg, dtype_bytes: int = 2) -> int:
+def _vmem_bytes(cfg, dtype_bytes: int = 4) -> int:
+    """Scoped VMEM of one variant: the pipeline double-buffers the x, w and
+    out tiles, plus the f32 accumulator. ``dtype_bytes=4`` bounds f32
+    operands too, so an admitted tile compiles for every operand dtype."""
     bm, bn, bk = cfg["bm"], cfg["bn"], cfg["bk"]
-    return bm * bk * dtype_bytes + bk * bn * dtype_bytes + bm * bn * (dtype_bytes + 4)
+    return 2 * (bm * bk + bk * bn + bm * bn) * dtype_bytes + bm * bn * 4
 
 
 MATMUL_SPACE = ParamSpace(
@@ -99,7 +102,7 @@ MATMUL_SPACE = ParamSpace(
     ],
     [
         Constraint(
-            lambda c: _vmem_bytes(c) <= TPU_V5E.vmem_bytes // 2,
+            lambda c: _vmem_bytes(c) <= SCOPED_VMEM_BYTES,
             "tile working set exceeds VMEM budget",
         )
     ],
@@ -107,14 +110,15 @@ MATMUL_SPACE = ParamSpace(
 
 
 def _matmul_heuristic(x, w):
-    """Shape-aware default ≈ what a hand-written library baseline would pick."""
+    """Shape-aware default ≈ what a hand-written library baseline would pick
+    (always inside the VMEM budget: 256x256x512 needs 2.75 MiB at f32)."""
     m, k = x.shape
     n = w.shape[1]
     pick = lambda d, cap: min(cap, max(8, 1 << (int(d) - 1).bit_length()))
     return {
-        "bm": min(pick(m, 256), 1024),
-        "bn": max(128, min(pick(n, 256), 1024)),
-        "bk": max(128, min(pick(k, 512), 2048)),
+        "bm": pick(m, 256),
+        "bn": max(128, pick(n, 256)),
+        "bk": max(128, pick(k, 512)),
     }
 
 

@@ -26,9 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
 from ..core import Constraint, DispatchSpec, ParamSpace, PowerOfTwoParam, tunable
-from ..core.platform import TPU_V5E
+from ..core.platform import SCOPED_VMEM_BYTES
 from . import ref
 
 
@@ -78,7 +77,8 @@ def expert_gemm_pallas(
         out_specs=pl.BlockSpec((1, bc, bn), lambda ie, i, j, kk: (ie, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, cp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bn), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -86,9 +86,11 @@ def expert_gemm_pallas(
     return out[:, :c, :n]
 
 
-def _vmem_bytes(cfg, dtype_bytes: int = 2) -> int:
+def _vmem_bytes(cfg, dtype_bytes: int = 4) -> int:
+    """Scoped VMEM of one variant, as for ``matmul``: double-buffered x, w
+    and out tiles plus the f32 accumulator, at f32 operands."""
     bc, bn, bk = cfg["bc"], cfg["bn"], cfg["bk"]
-    return bc * bk * dtype_bytes + bk * bn * dtype_bytes + bc * bn * (dtype_bytes + 4)
+    return 2 * (bc * bk + bk * bn + bc * bn) * dtype_bytes + bc * bn * 4
 
 
 EXPERT_GEMM_SPACE = ParamSpace(
@@ -99,7 +101,7 @@ EXPERT_GEMM_SPACE = ParamSpace(
     ],
     [
         Constraint(
-            lambda c: _vmem_bytes(c) <= TPU_V5E.vmem_bytes // 2,
+            lambda c: _vmem_bytes(c) <= SCOPED_VMEM_BYTES,
             "tile working set exceeds VMEM budget",
         )
     ],

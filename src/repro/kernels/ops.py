@@ -143,8 +143,8 @@ Semantics are otherwise unchanged: dispatch resolves through the *active*
 runtime, whose default policy reproduces the old precedence exactly —
 stored best variant for (platform, kernel, shape-bucket, dtype), else the
 campaign's 'few fit most' cover entry, else the shape heuristic, with the
-pure-jnp reference path when kernels are disabled (``REPRO_USE_PALLAS=0``
-or ``mode="reference"``).
+pure-jnp reference path when kernels are disabled (``mode="reference"``,
+or ``mode="auto"`` off-TPU without ``REPRO_USE_PALLAS=1``).
 
 Observability (``repro.obs``)
 -----------------------------

@@ -16,9 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
-from ..core import Constraint, DispatchSpec, ParamSpace, PowerOfTwoParam, tunable
-from ..core.platform import TPU_V5E
+from ..core import DispatchSpec, ParamSpace, PowerOfTwoParam, tunable
+from ..core.platform import SCOPED_VMEM_BYTES
 from . import ref
 
 
@@ -63,7 +62,10 @@ def rmsnorm_pallas(
             jax.ShapeDtypeStruct(xp.shape, x.dtype),
             jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
+            dimension_semantics=("parallel",),
+        ),
         interpret=interpret,
     )(xp, weight[None, :])
     if pad:
@@ -73,23 +75,26 @@ def rmsnorm_pallas(
     return out
 
 
-RMSNORM_SPACE = ParamSpace(
-    [PowerOfTwoParam("block_rows", 8, 4096)],
-    [
-        Constraint(
-            # x tile + out tile (dtype) + fp32 intermediate, d up to 8192
-            lambda c: c["block_rows"] * 8192 * 8 <= TPU_V5E.vmem_bytes // 2,
-            "row block exceeds VMEM budget at max d_model",
-        )
-    ],
-)
+# Whether a row block fits VMEM depends on d and the dtype, which a config
+# does not name: the grid models below carry the budget, checked at the
+# tuned shapes and dtype — the same budget the heuristic sizes by.
+RMSNORM_SPACE = ParamSpace([PowerOfTwoParam("block_rows", 8, 4096)])
+
+
+def _rmsnorm_vmem_bytes(block_rows: int, d: int, dtype_bytes: int = 4) -> int:
+    """Scoped VMEM of the backward, the larger of the two passes: the
+    double-buffered ct, x and dx row tiles, three f32 row temporaries, and
+    the double-buffered inverse-rms column padded to 128 f32 lanes."""
+    d_pad = -(-d // 128) * 128
+    return block_rows * (d_pad * (6 * dtype_bytes + 3 * 4) + 2 * 128 * 4)
 
 
 def _rmsnorm_heuristic(x, w):
-    rows, d = x.shape
-    target = max(8, min(1024, TPU_V5E.vmem_bytes // (2 * 8 * max(d, 1))))
+    """The largest row block up to 1024 whose backward fits VMEM."""
+    d, nbytes = x.shape[-1], jnp.dtype(x.dtype).itemsize
     p = 8
-    while p * 2 <= target:
+    while p < 1024 and (_rmsnorm_vmem_bytes(2 * p, d, nbytes)
+                        <= SCOPED_VMEM_BYTES):
         p *= 2
     return {"block_rows": p}
 
@@ -219,7 +224,10 @@ def rmsnorm_bwd_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         # the row grid carries the d_weight accumulator: sequential
-        compiler_params=_compat.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
+            dimension_semantics=("arbitrary",),
+        ),
         interpret=interpret,
     )(ct, x, weight[None, :], invrms.astype(jnp.float32)[:, None])
     return (dx[:rows] if pad else dx), dw[0]
@@ -253,8 +261,10 @@ def _rmsnorm_bwd_example():
     # ct, x and invrms are token-row-sharded. vjp="reference" (not "none"):
     # the oracle is plain differentiable jnp, so grad-of-grad can
     # differentiate *through* this gradient site.
+    # d_weight sums over the rows.
     dispatch=DispatchSpec(example=_rmsnorm_bwd_example,
-                          data_parallel_args=(0, 1, 3), vjp="reference"),
+                          data_parallel_args=(0, 1, 3), reduced_outputs=(1,),
+                          vjp="reference"),
 )
 def rmsnorm_bwd(ct, x, weight, invrms, *, block_rows: int, eps: float = 1e-6,
                 interpret: Optional[bool] = None):
@@ -290,6 +300,7 @@ def _rmsnorm_grid_model(config, shapes=None):
             RefModel("out", (br, d), row, (rp, d), role="out"),
             RefModel("invrms", (br, 1), row, (rp, 1), role="out"),
         ),
+        vmem_need=functools.partial(_rmsnorm_vmem_bytes, br, d),
     )
 
 
@@ -311,6 +322,7 @@ def _rmsnorm_bwd_grid_model(config, shapes=None):
             RefModel("dx", (br, d), row, (rp, d), role="out"),
             RefModel("dw", (1, d), w0, (1, d), role="out"),
         ),
+        vmem_need=functools.partial(_rmsnorm_vmem_bytes, br, d),
     )
 
 

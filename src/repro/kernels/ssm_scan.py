@@ -31,9 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
 from ..core import Constraint, DispatchSpec, ParamSpace, PowerOfTwoParam, tunable
-from ..core.platform import TPU_V5E
+from ..core.platform import SCOPED_VMEM_BYTES
 from . import ref
 
 
@@ -170,7 +169,8 @@ def ssm_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((block_d, ds), jnp.float32)],
         # the time grid dim carries the state scratch: sequential
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -191,7 +191,7 @@ SSM_SCAN_SPACE = ParamSpace(
     ],
     [
         Constraint(
-            lambda c: _scan_vmem_bytes(c) <= TPU_V5E.vmem_bytes // 2,
+            lambda c: _scan_vmem_bytes(c) <= SCOPED_VMEM_BYTES,
             "chunk x d_inner working set exceeds VMEM budget",
         )
     ],
@@ -280,6 +280,7 @@ def _ssm_scan_bwd_example():
     heuristic=_ssm_scan_bwd_heuristic,
     dispatch=DispatchSpec(example=_ssm_scan_bwd_example,
                           data_parallel_args=(0, 1, 2, 3, 4, 5, 7),
+                          reduced_outputs=(4,),     # dA sums over the batch
                           # Reference VJP: grad-of-grad differentiates through.
                           vjp="reference"),
 )
@@ -352,7 +353,8 @@ def ssm_update_pallas(
             jax.ShapeDtypeStruct((bp, dip), jnp.float32),
             jax.ShapeDtypeStruct((bp, dip, ds), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -368,7 +370,7 @@ SSM_UPDATE_SPACE = ParamSpace(
     [
         Constraint(
             lambda c: c["block_b"] * c["block_d"] * 16 * 8 + c["block_b"]
-            * c["block_d"] * 12 <= TPU_V5E.vmem_bytes // 2,
+            * c["block_d"] * 12 <= SCOPED_VMEM_BYTES,
             "decode-state tile exceeds VMEM budget",
         )
     ],
@@ -446,6 +448,7 @@ def _ssm_update_bwd_example():
     heuristic=_ssm_update_bwd_heuristic,
     dispatch=DispatchSpec(example=_ssm_update_bwd_example,
                           data_parallel_args=(0, 1, 2, 3, 4, 5, 7),
+                          reduced_outputs=(4,),     # dA sums over the batch
                           # Reference VJP: grad-of-grad differentiates through.
                           vjp="reference"),
 )

@@ -18,9 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
 from ..core import Constraint, DispatchSpec, ParamSpace, PowerOfTwoParam, tunable
-from ..core.platform import TPU_V5E
+from ..core.platform import SCOPED_VMEM_BYTES
 from . import ref
 
 _NEG_INF = -1e30
@@ -109,7 +108,8 @@ def softmax_xent_pallas(
             pltpu.VMEM((block_rows, 1), jnp.float32),
             pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -119,6 +119,15 @@ def softmax_xent_pallas(
     return loss[:rows, 0]
 
 
+def _xent_vmem_bytes(cfg, dtype_bytes: int = 4) -> int:
+    """Scoped VMEM of the larger of the two passes (the backward): the
+    double-buffered logits and d_logits tiles, two f32 tile temporaries, and
+    the [rows, 1] columns (labels, ct, lse, loss, scratch), each padded to
+    a full 128-lane f32 row. ``dtype_bytes=4`` bounds f32 logits too."""
+    br, bv = cfg["block_rows"], cfg["block_v"]
+    return br * bv * (4 * dtype_bytes + 8) + 9 * br * 128 * 4
+
+
 XENT_SPACE = ParamSpace(
     [
         PowerOfTwoParam("block_rows", 8, 1024),
@@ -126,7 +135,7 @@ XENT_SPACE = ParamSpace(
     ],
     [
         Constraint(
-            lambda c: c["block_rows"] * c["block_v"] * 6 <= TPU_V5E.vmem_bytes // 2,
+            lambda c: _xent_vmem_bytes(c) <= SCOPED_VMEM_BYTES,
             "xent tile exceeds VMEM budget",
         )
     ],
@@ -134,9 +143,14 @@ XENT_SPACE = ParamSpace(
 
 
 def _xent_heuristic(logits, labels):
+    """8192-wide vocab blocks, then the most rows (up to 256) the VMEM
+    budget admits: 64 at 8192."""
     rows, vocab = logits.shape
-    return {"block_rows": min(256, max(8, 1 << (int(rows) - 1).bit_length() if rows < 256 else 256)),
-            "block_v": min(8192, max(512, vocab if vocab < 512 else 8192))}
+    cfg = {"block_v": 512 if vocab < 512 else 8192,
+           "block_rows": min(256, max(8, 1 << (int(rows) - 1).bit_length()))}
+    while cfg["block_rows"] > 8 and not XENT_SPACE.is_valid(cfg):
+        cfg["block_rows"] //= 2
+    return cfg
 
 
 def _xent_example():
@@ -240,7 +254,8 @@ def softmax_xent_bwd_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, block_v), lambda ri, vi: (ri, vi)),
         out_shape=jax.ShapeDtypeStruct((rp, vp), logits.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SCOPED_VMEM_BYTES,
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -16,12 +16,8 @@ import jax
 
 
 def _mk_mesh(shape, axes, devices) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType (explicit-sharding API) does not exist in older
-    # jax; Auto is also the default there, so omitting axis_types is exact.
-    if hasattr(jax.sharding, "AxisType"):
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, devices=devices, axis_types=auto)
-    return jax.make_mesh(shape, axes, devices=devices)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, devices=devices, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

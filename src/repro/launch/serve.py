@@ -6,6 +6,9 @@ model traces resolves against it — no process-global state — and the run
 ends with the runtime's telemetry report (which resolution tier served each
 kernel×bucket: the sustained-performance accounting).
 
+    # one chip, full widths, kernel path, prompts of 16..300 tokens:
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \\
+        --mesh 1x1 --mode kernel --max-seq 512 --max-prompt 300
     # pod, with a campaign artifact:
     python -m repro.launch.serve --arch qwen2.5-3b --requests 64 \\
         --db tpu-v5e.json --warmup
@@ -15,8 +18,10 @@ kernel×bucket: the sustained-performance accounting).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import os
+from typing import List, Optional
 
 import jax
 import numpy as np
@@ -27,17 +32,26 @@ from ..core.database import TuningDatabase
 from ..models import lm
 from ..serving.engine import EngineConfig, Request, ServingEngine
 from . import defaults
-from .mesh import make_host_mesh, make_production_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import make_host_mesh, make_mesh_from_spec, make_production_mesh
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=128)
+    ap.add_argument("--max-prompt", type=int, default=16,
+                    help="prompt lengths spread from 16 up to this many tokens")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and of the prompts")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--mesh", default=None,
+                    help="explicit mesh spec DATAxMODEL (e.g. 1x1) over the "
+                         "available devices; overrides the smoke/production "
+                         "mesh choice")
     ap.add_argument("--db", default=None,
                     help="campaign-exported tuning database for this platform")
     ap.add_argument("--mode", default="auto",
@@ -57,23 +71,25 @@ def main():
                          "`python -m repro.obs report --metrics <file>`)")
     ap.add_argument("--metrics-sample", type=float, default=1.0,
                     help="obs sample rate for per-tick gauges (1.0 = all)")
-    args = ap.parse_args()
-    if args.platform:
-        from ..core.platform import set_platform_override
+    return ap
 
-        set_platform_override(args.platform)
-    if args.db and not os.path.exists(args.db):
-        # A typo'd path would otherwise open as an EMPTY database and every
-        # bucket would silently resolve at the heuristic tier — the exact
-        # wasted-artifact failure warmup exists to prevent.
-        ap.error(f"--db {args.db}: no such file")
 
+def build(args, runtime: Optional[repro.TunedRuntime] = None):
+    """(cfg, engine, runtime) for parsed launcher ``args``.
+
+    The engine runs under ``runtime`` when one is given, else under a
+    runtime made from ``--db``/``--mode``. Weights are random, drawn from
+    ``--seed``.
+    """
     cfg = get_config(args.arch)
     shape = SHAPES["decode_32k"]
     if args.smoke:
         cfg = cfg.reduced()
         if cfg.num_experts:
             cfg = dataclasses.replace(cfg, capacity_factor=8.0)
+    if args.mesh:
+        mesh = make_mesh_from_spec(args.mesh)
+    elif args.smoke:
         mesh = make_host_mesh()
     else:
         mesh = make_production_mesh(multi_pod=args.multi_pod)
@@ -84,8 +100,10 @@ def main():
             run, q_chunk=32, k_chunk=max(32, args.max_seq), loss_chunk=32
         )
 
-    params, _ = lm.init_params(jax.random.PRNGKey(0), cfg)
-    rt = repro.runtime(
+    params = jax.jit(lambda k: lm.init_params(k, cfg)[0])(
+        jax.random.PRNGKey(args.seed)
+    )
+    rt = runtime or repro.runtime(
         db=TuningDatabase(args.db) if args.db else None,
         mode=args.mode, name="serve",
     )
@@ -94,7 +112,44 @@ def main():
         EngineConfig(max_batch=8, max_seq=args.max_seq),
         runtime=rt,
     )
-    import contextlib
+    return cfg, engine, rt
+
+
+def make_requests(cfg, args) -> List[Request]:
+    """``--requests`` staggered requests (arrival every tick, exercising
+    in-flight admission), alternating greedy and sampled decoding, with
+    prompt lengths log-uniform in [16, ``--max-prompt``]."""
+    rs = np.random.RandomState(args.seed)
+    hi = max(16, args.max_prompt)
+    lens = np.exp(rs.uniform(np.log(16), np.log(hi), args.requests)).astype(int)
+    lens[:1] = 16
+    lens[-1:] = hi
+    return [
+        Request(
+            prompt=rs.randint(0, cfg.vocab_size, n).astype(np.int32),
+            max_new_tokens=args.new_tokens,
+            temperature=0.7 if i % 2 else 0.0,
+            seed=i,
+            arrival_time=float(i),
+        )
+        for i, n in enumerate(lens)
+    ]
+
+
+def main(argv=None):
+    ap = parser()
+    args = ap.parse_args(argv)
+    if args.platform:
+        from ..core.platform import set_platform_override
+
+        set_platform_override(args.platform)
+    if args.db and not os.path.exists(args.db):
+        # A typo'd path would otherwise open as an EMPTY database and every
+        # bucket would silently resolve at the heuristic tier — the exact
+        # wasted-artifact failure warmup exists to prevent.
+        ap.error(f"--db {args.db}: no such file")
+    enable_compile_cache()
+    cfg, engine, rt = build(args)
 
     import repro.obs as obs
     from ..obs.metrics import percentile_row
@@ -107,17 +162,8 @@ def main():
         if args.warmup:
             resolved = engine.warmup()
             print(f"warmup resolved {len(resolved)} kernel buckets")
-        rs = np.random.RandomState(0)
-        for i in range(args.requests):
-            engine.submit(
-                Request(
-                    prompt=rs.randint(0, cfg.vocab_size, 16).astype(np.int32),
-                    max_new_tokens=args.new_tokens,
-                    temperature=0.7 if i % 2 else 0.0,
-                    seed=i,
-                    arrival_time=float(i),  # staggered: exercises in-flight admission
-                )
-            )
+        for req in make_requests(cfg, args):
+            engine.submit(req)
         done = engine.serve()
     toks = sum(len(r.output) for r in done)
     st = engine.stats

@@ -14,6 +14,9 @@ the trainer traces under its mesh context, those buckets are keyed on
 per-device *local* shard shapes: the shapes ``campaign plan --train-mesh``
 pre-tunes.
 
+    # one chip, full widths, kernel path, 8 x 1024 tokens per step:
+    PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \\
+        --mesh 1x1 --batch 8 --seq-len 1024 --steps 3 --mode kernel
     # pod (256 chips), with a campaign artifact:
     python -m repro.launch.train --arch mixtral-8x7b --shape train_4k \\
         --steps 1000 --db tpu-v5e.json --mode kernel
@@ -23,8 +26,11 @@ pre-tunes.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import dataclasses
 import logging
 import os
+from typing import Optional
 
 import jax
 
@@ -36,15 +42,20 @@ from ..data.pipeline import DataConfig
 from ..optim import adamw
 from ..train.trainer import Trainer, TrainerConfig
 from . import defaults
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh, make_mesh_from_spec, make_production_mesh
 
-logging.basicConfig(level=logging.INFO, format="%(name)s: %(message)s")
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k", choices=list(SHAPES))
+    ap.add_argument("--batch", type=int, default=None,
+                    help="global batch (sequences per step); default: the "
+                         "shape's")
+    ap.add_argument("--seq-len", type=int, default=None,
+                    help="tokens per sequence; default: the shape's")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config + host mesh (CPU dev box)")
@@ -75,38 +86,69 @@ def main():
                          "`python -m repro.obs report --metrics <file>`)")
     ap.add_argument("--metrics-sample", type=float, default=1.0,
                     help="obs sample rate for high-frequency sites (1.0 = all)")
-    args = ap.parse_args()
+    return ap
+
+
+def build(args, runtime: Optional[repro.TunedRuntime] = None):
+    """(trainer, runtime) for parsed launcher ``args``; the trainer runs
+    under ``runtime`` when one is given, else under one made from
+    ``--db``/``--mode``."""
+    cfg = get_config(args.arch)
+    shape = SHAPES["train_smoke" if args.smoke else args.shape]
+    if args.smoke:
+        cfg = cfg.reduced()
+    if args.mesh:
+        mesh = make_mesh_from_spec(args.mesh)
+    elif args.smoke:
+        mesh = make_host_mesh()
+    else:
+        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    shape = dataclasses.replace(
+        shape,
+        global_batch=args.batch or shape.global_batch,
+        seq_len=args.seq_len or shape.seq_len,
+    )
+    run = defaults.default_run(cfg, shape)
+    layout = defaults.default_layout(cfg, args.multi_pod)
+
+    rt = runtime or repro.runtime(
+        db=TuningDatabase(args.db) if args.db else None,
+        mode=args.mode, name="train",
+    )
+    trainer = Trainer(
+        cfg, run, mesh, layout,
+        DataConfig(seed=args.seed, batch_size=shape.global_batch,
+                   seq_len=shape.seq_len,
+                   host_index=jax.process_index(),
+                   host_count=jax.process_count()),
+        adamw.AdamWConfig(total_steps=args.steps),
+        TrainerConfig(
+            total_steps=args.steps,
+            checkpoint_every=args.ckpt_every,
+            checkpoint_dir=args.ckpt_dir,
+            grad_compression=args.compression,
+            seed=args.seed,
+        ),
+        runtime=rt,
+    )
+    return trainer, rt
+
+
+def main(argv=None):
+    logging.basicConfig(level=logging.INFO, format="%(name)s: %(message)s")
+    ap = parser()
+    args = ap.parse_args(argv)
     if args.db and not os.path.exists(args.db):
         # A typo'd path would otherwise open as an EMPTY database and every
         # bucket would silently resolve at the heuristic tier.
         ap.error(f"--db {args.db}: no such file")
     if args.platform:
         set_platform_override(args.platform)
+    enable_compile_cache()
 
-    cfg = get_config(args.arch)
-    shape = SHAPES[args.shape]
-    if args.smoke:
-        cfg = cfg.reduced()
-        mesh = make_mesh_from_spec(args.mesh) if args.mesh else make_host_mesh()
-        shape = SHAPES["train_smoke"]
-        batch, seq = shape.global_batch, shape.seq_len
-        run = defaults.default_run(cfg, shape)
-    else:
-        mesh = (make_mesh_from_spec(args.mesh) if args.mesh
-                else make_production_mesh(multi_pod=args.multi_pod))
-        batch, seq = shape.global_batch, shape.seq_len
-        run = defaults.default_run(cfg, shape)
-    layout = defaults.default_layout(cfg, args.multi_pod)
-
-    rt = repro.runtime(
-        db=TuningDatabase(args.db) if args.db else None,
-        mode=args.mode, name="train",
-    )
     # Observability is opt-in: without --metrics-out the ambient collector
     # stays the disabled process default and instrumentation costs one
     # branch per site (the overhead contract).
-    import contextlib
-
     import repro.obs as obs
 
     col = (
@@ -114,21 +156,7 @@ def main():
         if args.metrics_out else contextlib.nullcontext()
     )
     with col:
-        trainer = Trainer(
-            cfg, run, mesh, layout,
-            DataConfig(seed=args.seed, batch_size=batch, seq_len=seq,
-                       host_index=jax.process_index(),
-                       host_count=jax.process_count()),
-            adamw.AdamWConfig(total_steps=args.steps),
-            TrainerConfig(
-                total_steps=args.steps,
-                checkpoint_every=args.ckpt_every,
-                checkpoint_dir=args.ckpt_dir,
-                grad_compression=args.compression,
-                seed=args.seed,
-            ),
-            runtime=rt,
-        )
+        trainer, rt = build(args)
         # resume if a checkpoint exists
         if trainer.ckpt.latest_step() is not None:
             trainer.restore_checkpoint()

@@ -126,8 +126,9 @@ class Checkpointer:
                         "crc32": zlib.crc32(arr.tobytes()) & 0xFFFFFFFF,
                     }
                 )
-            manifest["treedef"] = jax.tree_util.tree_structure(host_tree).serialize_using_proto().hex() \
-                if hasattr(jax.tree_util.tree_structure(host_tree), "serialize_using_proto") else None
+            manifest["treedef"] = (
+                jax.tree_util.tree_structure(host_tree).serialize_using_proto().hex()
+            )
             with open(os.path.join(stage, "manifest.json"), "w") as f:
                 json.dump(manifest, f)
                 f.flush()

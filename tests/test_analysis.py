@@ -345,7 +345,7 @@ def test_campaign_status_prints_pruned_counts(tmp_path, capsys):
     out = capsys.readouterr().out
     assert rc == 0
     assert '"configs_pruned": 28' in out
-    assert "pruned 28 of 49 configs (21 legal) on tpu-v5e" in out
+    assert "pruned 28 of 46 configs (18 legal) on tpu-v5e" in out
 
 
 # ---------------------------------------------------------------------------

@@ -44,11 +44,7 @@ _RING_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.collectives import ring_all_reduce
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((8,), ("x",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    else:
-        mesh = jax.make_mesh((8,), ("x",))
+    mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
     x = jnp.asarray(np.random.RandomState(0).randn(8, 37), jnp.float32)
     out = jax.jit(lambda v: ring_all_reduce(v, mesh, "x"))(x)
     want = jnp.broadcast_to(x.sum(0), x.shape)

@@ -77,3 +77,28 @@ def test_tolerance_scale_uses_finite_reference_magnitude():
     # the finite magnitude (100) scales atol; a 2e-3 absolute error passes f32
     out2 = np.array([np.inf, 100.0005, -100.0], np.float32)
     assert correctness_gate(out2, ref)
+
+
+def test_strict_evaluator_raises_where_lenient_scores_inf():
+    import math
+
+    import pytest
+
+    from repro.core.evaluate import WallClockEvaluator
+
+    x = jnp.ones((4, 4), jnp.float32)
+
+    def broken(a):
+        raise ValueError("refused tile")
+
+    wrong = lambda a: a + 1.0
+    lenient = WallClockEvaluator(repeats=1, warmup=0)
+    strict = WallClockEvaluator(repeats=1, warmup=0, strict=True)
+    for fn, ref in ((broken, None), (wrong, x)):
+        m = lenient.evaluate(fn, (x,), reference=ref)
+        assert not m.ok and math.isinf(m.objective)
+    with pytest.raises(ValueError, match="refused tile"):
+        strict.evaluate(broken, (x,))
+    with pytest.raises(AssertionError, match="correctness gate"):
+        strict.evaluate(wrong, (x,), reference=x)
+    assert strict.evaluate(lambda a: a * 1.0, (x,), reference=x).ok

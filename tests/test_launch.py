@@ -46,11 +46,8 @@ _MINI_DRYRUN = textwrap.dedent(
     from repro.launch import steps
     from repro.models.transformer import RunConfig
 
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("qwen2-0.5b").reduced()
     layout = Layout(counts=(("heads", cfg.num_heads), ("kv_heads", cfg.num_kv_heads)),
                     head_aware=True)
@@ -77,6 +74,44 @@ _MINI_DRYRUN = textwrap.dedent(
     print("MINI_DRYRUN_JSON=" + json.dumps(results))
     """
 )
+
+
+def test_serve_launcher_builds_engine_on_explicit_mesh():
+    """The serve launcher's own builder (what ``chip_smoke.py`` drives):
+    ``--mesh`` picks the mesh, requests spread from 16 tokens up to
+    ``--max-prompt``, and the engine serves them on the kernel path."""
+    import repro
+    from repro.core.database import TuningDatabase
+    from repro.launch import serve
+
+    args = serve.parser().parse_args([
+        "--arch", "qwen2-0.5b", "--smoke", "--mesh", "1x1", "--requests", "3",
+        "--new-tokens", "2", "--max-seq", "64", "--max-prompt", "40",
+    ])
+    rt = repro.runtime(db=TuningDatabase(None), mode="kernel", guard=False)
+    cfg, engine, got_rt = serve.build(args, runtime=rt)
+    assert got_rt is rt and engine.mesh.devices.shape == (1, 1)
+    reqs = serve.make_requests(cfg, args)
+    assert [len(r.prompt) for r in reqs][::2] == [16, 40]
+    for r in reqs:
+        engine.submit(r)
+    done = engine.serve()
+    assert [len(r.output) for r in done] == [2, 2, 2]
+    tiers = rt.telemetry.snapshot()["tiers"]
+    assert set(tiers) == {"heuristic"}, tiers
+
+
+def test_train_launcher_takes_batch_and_seq_len(tmp_path):
+    from repro.launch import train
+
+    args = train.parser().parse_args([
+        "--arch", "qwen2-0.5b", "--smoke", "--mesh", "1x1", "--batch", "4",
+        "--seq-len", "32", "--steps", "1", "--mode", "reference",
+        "--ckpt-dir", str(tmp_path / "ckpt"), "--ckpt-every", "1000",
+    ])
+    trainer, _ = train.build(args)
+    batch = trainer.data.next_batch()
+    assert batch["tokens"].shape == (4, 32)
 
 
 def test_mini_dryrun_all_step_kinds():

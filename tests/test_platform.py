@@ -91,3 +91,24 @@ def test_runtime_platform_param_pins_namespace():
     # nested runtimes inherit the pinned platform
     with repro.runtime(platform="tpu-v5e"):
         assert repro.runtime().platform == "tpu-v5e"
+
+
+@pytest.mark.parametrize("kind,want", [
+    ("TPU v5 lite", "tpu-v5e"),
+    ("TPU v4", "tpu-v4"),
+    ("TPU v6 lite", None),
+])
+def test_fingerprint_keys_profiles_by_device_kind(monkeypatch, kind, want):
+    """TPU profiles come from the device_kind table; a kind without peaks
+    is an error, never another chip's numbers."""
+    import types
+
+    import jax
+
+    dev = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [dev])
+    if want is None:
+        with pytest.raises(ValueError, match="TPU v6 lite"):
+            detect_platform()
+    else:
+        assert detect_platform() is PROFILES[want]

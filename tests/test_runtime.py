@@ -556,3 +556,19 @@ def test_call_rejects_constraint_violation():
     with pytest.raises(ValueError, match="a must not exceed b"):
         toy(jnp.ones(4), a=64, b=8)
     assert toy(jnp.ones(4), a=8, b=64) is not None
+
+
+def test_auto_mode_takes_the_kernel_path_on_tpu(monkeypatch):
+    """``mode="auto"`` dispatches kernels on a TPU backend; elsewhere it
+    needs ``REPRO_USE_PALLAS=1``."""
+    import repro.core.runtime as rt_mod
+
+    monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+    auto = repro.runtime(mode="auto")
+    monkeypatch.setattr(rt_mod, "_backend_tpu", False)
+    assert not auto.kernel_mode_active
+    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    assert auto.kernel_mode_active
+    monkeypatch.delenv("REPRO_USE_PALLAS")
+    monkeypatch.setattr(rt_mod, "_backend_tpu", True)
+    assert auto.kernel_mode_active
