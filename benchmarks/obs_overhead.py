@@ -9,7 +9,7 @@ both:
 
 * ``step.*`` — a jitted kernel-mode fwd+bwd step (matmul + rmsnorm through
   ``repro.dispatch``, gradients included) vs the per-step cost of exactly
-  the obs calls the trainer adds around it (span + observe + counter),
+  the obs calls the trainer adds around it (span + counter),
   measured in isolation where microsecond precision is possible; overhead
   is their ratio (see :func:`bench_step` for why not A+B-vs-B timing).
 * ``resolve.*`` — the eager dispatch-resolution hot path (where the obs
@@ -48,7 +48,7 @@ def bench_step(quick: bool = False) -> Dict:
     measure the two quantities whose ratio *is* the overhead, each where it
     can be measured precisely: the kernel-mode step time (min-of-rounds over
     the jitted fwd+bwd), and the per-step cost of exactly the obs calls the
-    trainer adds around it (span + observe + counter, timed in isolation
+    trainer adds around it (span + counter, timed in isolation
     over thousands of iterations). ``overhead = instr_cost / step_time`` is
     an upper bound on the added fraction — the obs calls do the same work
     whether or not a jitted call sits inside the span.
@@ -83,12 +83,10 @@ def bench_step(quick: bool = False) -> Dict:
     def instr_only():
         # exactly what Trainer.run_one_step wraps around the jitted step,
         # with the step itself removed
-        t0 = time.perf_counter()
         with span("train.step"):
             pass
         col = current_collector()
         if col.enabled:
-            col.observe("train.step_s", time.perf_counter() - t0)
             col.counter("train.tokens", x.shape[0])
 
     rounds, steps = (3, 10) if quick else (5, 30)

@@ -161,9 +161,9 @@ collector* — ``repro.obs.collect(...)`` scoped the same contextvar way as
   ``jax.profiler.TraceAnnotation`` so they show up in XLA profiles.
 * **Metrics** — counters / gauges / log-bucketed histograms (p50/p95/p99
   in bounded memory). Built-in hot-path series: ``dispatch.resolve_s``
-  (per-tier, cache hit/miss), ``dispatch.calls``, ``train.step_s`` /
-  ``train.tokens_per_s``, ``serve.admission_s`` / ``serve.per_token_s`` /
-  ``serve.queue_depth``, ``campaign.job_s`` / ``campaign.speedup``.
+  (per-tier, cache hit/miss), ``dispatch.calls``, ``span.train.step`` /
+  ``train.tokens_per_s``, ``span.serve.admit`` / ``span.serve.tick`` /
+  ``serve.per_token_s``, ``campaign.job_s`` / ``campaign.speedup``.
 * **Drift** — ``python -m repro.obs report --drift --db <db>`` (or
   ``python -m repro.campaign drift``) replays each stored record's winning
   config, attributes live seconds to %-of-tuned-best and %-of-roofline

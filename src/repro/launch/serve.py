@@ -69,8 +69,6 @@ def parser() -> argparse.ArgumentParser:
                     help="enable the obs collector for the run and write its "
                          "snapshot JSON here (render with "
                          "`python -m repro.obs report --metrics <file>`)")
-    ap.add_argument("--metrics-sample", type=float, default=1.0,
-                    help="obs sample rate for per-tick gauges (1.0 = all)")
     return ap
 
 
@@ -155,7 +153,7 @@ def main(argv=None):
     from ..obs.metrics import percentile_row
 
     col = (
-        obs.collect(name="serve", sample_rate=args.metrics_sample)
+        obs.collect(name="serve")
         if args.metrics_out else contextlib.nullcontext()
     )
     with col:
@@ -167,14 +165,16 @@ def main(argv=None):
         done = engine.serve()
     toks = sum(len(r.output) for r in done)
     st = engine.stats
+    ttft = [r.first_token_s - r.submitted_s for r in done]
     print(f"served {len(done)} requests / {toks} tokens; "
           f"p50 latency {sorted(r.latency_s for r in done)[len(done)//2]:.2f}s "
           f"({sorted(r.latency_steps for r in done)[len(done)//2]} ticks); "
+          f"TTFT p50 {np.percentile(ttft, 50):.2f}s p95 {np.percentile(ttft, 95):.2f}s; "
           f"{st['decode_steps']} pool decode steps, "
           f"{st['tokens_out']/max(1, st['decode_steps']):.2f} tok/step")
     if args.metrics_out:
         snap = col.snapshot()
-        for name, label in (("serve.admission_s", "admission"),
+        for name, label in (("span.serve.admit", "admission"),
                             ("serve.per_token_s", "per-token"),
                             ("serve.latency_s", "request latency")):
             row = percentile_row(snap, name)

@@ -84,8 +84,6 @@ def parser() -> argparse.ArgumentParser:
                     help="enable the obs collector for the run and write its "
                          "snapshot JSON here (render with "
                          "`python -m repro.obs report --metrics <file>`)")
-    ap.add_argument("--metrics-sample", type=float, default=1.0,
-                    help="obs sample rate for high-frequency sites (1.0 = all)")
     return ap
 
 
@@ -152,7 +150,7 @@ def main(argv=None):
     import repro.obs as obs
 
     col = (
-        obs.collect(name="train", sample_rate=args.metrics_sample)
+        obs.collect(name="train")
         if args.metrics_out else contextlib.nullcontext()
     )
     with col:

@@ -17,16 +17,15 @@ non-divisible-microbatch key approximation) and records + logs exactly once
 per (collector, name, key) even when metric collection is off, so the
 hazard is never silently dropped.
 
-Sampling: high-frequency call sites (per-token serving paths) gate on
+Sampling: a high-frequency call site can gate on
 :meth:`ObsCollector.sample`, a deterministic 1-in-N tick driven by
 ``sample_rate`` — the "default sampling" configuration is ``1.0`` (record
-everything); a loaded fleet dials it down without touching call sites.
+everything).
 """
 from __future__ import annotations
 
 import collections
 import contextvars
-import itertools
 import logging
 import threading
 import time
@@ -38,15 +37,15 @@ log = logging.getLogger("repro.obs")
 
 _EVENT_KINDS = ("event", "span", "warning")
 
-_span_ids = itertools.count(1)
-
 
 class Event(dict):
     """One structured event: a plain dict (JSONL-friendly) with a schema.
 
     Keys: ``ts`` (unix seconds), ``kind`` (``event | span | warning``),
     ``name``, plus free-form fields; span events carry ``span_id`` /
-    ``parent_id`` / ``dur_s`` so a tree can be rebuilt offline.
+    ``parent_id`` / ``dur_s`` so a tree can be rebuilt offline, and
+    ``start_ns`` / ``end_ns`` (``time.time_ns()``) so it can be laid beside a
+    profile.
     """
 
 

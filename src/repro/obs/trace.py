@@ -12,14 +12,18 @@ Each completed span lands twice on the ambient collector:
   deliberately NOT attached to the histogram: span callers pass per-call
   fields (step numbers, request ids) whose cardinality would explode the
   registry; those go on the event instead.
-* event ``kind="span"`` — ``{name, dur_s, span_id, parent_id, **tags}`` in
-  the bounded ring buffer.
+* event ``kind="span"`` — ``{name, dur_s, start_ns, end_ns, span_id,
+  parent_id, **tags}`` in the bounded ring buffer. ``start_ns`` / ``end_ns``
+  are ``time.time_ns()``, the wall clock the profiler stamps its host events
+  with (an XLA profile's host event sits at the profile's
+  ``profile_start_time`` plus its own ``start_ns``), so a ``--metrics-out``
+  event log can be laid beside a profile.
 
 Opt-in XLA visibility: a collector created with ``xla_annotations=True``
-wraps every span in ``jax.profiler.TraceAnnotation``, so spans show up on
-the host timeline of an XLA profile next to the device ops they enclose.
-Failure to import/enter the annotation is swallowed — tracing must never
-take down the workload.
+wraps every span in ``jax.profiler.TraceAnnotation`` carrying the span's
+tags as event stats, so spans show up on the host timeline of an XLA
+profile next to the device ops they enclose. Failure to import/enter the
+annotation is swallowed — tracing must never take down the workload.
 
 A disabled collector short-circuits before any allocation: the span body
 runs bare, and ``yield`` sees ``None``.
@@ -51,6 +55,7 @@ class Span:
     parent_id: Optional[int]
     tags: Dict[str, Any]
     t0: float = 0.0
+    start_ns: int = 0
 
     def set(self, **fields: Any) -> None:
         """Attach fields to the span's completion event."""
@@ -81,15 +86,17 @@ def span(name: str, **tags: Any) -> Iterator[Optional[Span]]:
         try:
             from jax.profiler import TraceAnnotation
 
-            ann = TraceAnnotation(name)
+            ann = TraceAnnotation(name, **sp.tags)
             ann.__enter__()
         except Exception:
             ann = None
+    sp.start_ns = time.time_ns()
     sp.t0 = time.perf_counter()
     try:
         yield sp
     finally:
         dur = time.perf_counter() - sp.t0
+        end_ns = time.time_ns()
         if ann is not None:
             try:
                 ann.__exit__(None, None, None)
@@ -98,8 +105,8 @@ def span(name: str, **tags: Any) -> Iterator[Optional[Span]]:
         _span_ctx.reset(tok)
         col.observe(f"span.{name}", dur)
         col.event(
-            name, kind="span", dur_s=dur, span_id=sp.span_id,
-            parent_id=sp.parent_id, **sp.tags,
+            name, kind="span", dur_s=dur, start_ns=sp.start_ns, end_ns=end_ns,
+            span_id=sp.span_id, parent_id=sp.parent_id, **sp.tags,
         )
 
 

@@ -46,7 +46,25 @@ Timing: the engine has a virtual tick clock (1 tick = one pool decode
 step; ``Request.arrival_time`` is in ticks) for deterministic scheduling
 tests, and an injectable wall clock for latency. ``latency_s`` measures
 admission → the request's own last token, so late-admitted requests are
-not charged for time they spent unqueued or for earlier occupants' work.
+not charged for time they spent unqueued or for earlier occupants' work;
+``submitted_s`` and ``first_token_s`` are read off the same clock, so
+``first_token_s - submitted_s`` is the time to first token.
+
+Spans (``repro.obs``; free when no collector is enabled), all on the
+thread that calls :meth:`ServingEngine.serve`, and never overlapping at the
+top level:
+
+    serve.admit   — one admission: prefill, the first token's sample (which
+                    waits for the prefill to run) and the cache insert's
+                    enqueue; tags ``request`` (submission order), ``slot``,
+                    ``prompt_len``, ``bucket``;
+    serve.tick    — one pool decode step, from building its inputs to the
+                    last retirement; tags ``tick`` (``decode_steps`` before
+                    it), ``active``, ``queued``; its children:
+      serve.decode  — building the token/position arrays and enqueueing the
+                      decode step;
+      serve.fetch   — the logits to the host (waits for the decode step);
+      serve.sample  — per-slot sampling and retirement.
 """
 from __future__ import annotations
 
@@ -78,6 +96,8 @@ class Request:
     arrival_time: float = 0.0       # engine ticks (decode steps); 0 = already here
     # filled by the engine:
     output: Optional[np.ndarray] = None
+    submitted_s: float = 0.0        # engine clock at submit()
+    first_token_s: float = 0.0      # engine clock when the first token was sampled
     latency_s: float = 0.0          # admission -> THIS request's last token (wall)
     latency_steps: int = 0          # admission -> last token, in decode ticks
     queue_steps: int = 0            # arrival -> admission, in decode ticks
@@ -230,7 +250,7 @@ class ServingEngine:
     def _run_prefill(self, toks, L):
         if not self.degraded:
             try:
-                with self._scope(), _obs_span("serve.admit.prefill"):
+                with self._scope():
                     return self._prefill(self.params, toks, L)
             except Exception as e:  # fault mid-admission: demote, complete
                 self._note_degraded("prefill", e)
@@ -287,6 +307,7 @@ class ServingEngine:
                 col.counter("serve.shed", reason="queue_full")
             return False
         req._order = self._order          # submission order, for serve()'s return
+        req.submitted_s = self.clock()
         self._order += 1
         self.queue.append(req)
         return True
@@ -301,38 +322,37 @@ class ServingEngine:
 
     # ------------------------------------------------------------- admission
     def _admit(self, req: Request, slot: int, now: int, done: List[Request]) -> None:
-        t_wall = time.perf_counter()
         L = len(req.prompt)
         sb = self._bucket_len(L)
-        toks = np.zeros((1, sb), np.int32)
-        toks[0, :L] = req.prompt
-        with _obs_span("serve.admit", slot=slot, prompt_len=L):
+        with _obs_span("serve.admit", request=req._order, slot=slot,
+                       prompt_len=L, bucket=sb):
+            toks = np.zeros((1, sb), np.int32)
+            toks[0, :L] = req.prompt
             logits, cache = self._run_prefill(
                 jnp.asarray(toks), jnp.asarray(L, jnp.int32)
             )
-        self.stats["prefill_calls"] += 1
-        self.stats["prefill_tokens"] += sb
+            self.stats["prefill_calls"] += 1
+            self.stats["prefill_tokens"] += sb
 
-        req.admitted_step = now
-        req.queue_steps = max(0, now - int(np.ceil(req.arrival_time)))
-        req.slot = slot
-        t_admit = self.clock()
-        rng = np.random.default_rng(req.seed)
-        first = _sample_one(np.asarray(logits, np.float32)[0], req, rng)
-        col = _obs_collector()
-        if col.enabled:
-            # admission → first token: prefill + the first sample, wall time.
-            col.observe("serve.admission_s", time.perf_counter() - t_wall)
-            col.counter("serve.requests")
-        max_new = min(req.max_new_tokens, self.ecfg.max_seq - L)
-        state = _Slot(req=req, rng=rng, cur=first, pos=L, max_new=max_new,
-                      emitted=[first], t_admit=t_admit)
-        if len(state.emitted) >= max_new:
-            self._finish(state, now)      # one-token request: never occupies
-            done.append(req)
-            return
-        self._caches = self._insert(self._caches, cache, jnp.asarray(slot, jnp.int32))
-        self._slots[slot] = state
+            req.admitted_step = now
+            req.queue_steps = max(0, now - int(np.ceil(req.arrival_time)))
+            req.slot = slot
+            t_admit = self.clock()
+            rng = np.random.default_rng(req.seed)
+            first = _sample_one(np.asarray(logits, np.float32)[0], req, rng)
+            req.first_token_s = self.clock()
+            col = _obs_collector()
+            if col.enabled:
+                col.counter("serve.requests")
+            max_new = min(req.max_new_tokens, self.ecfg.max_seq - L)
+            state = _Slot(req=req, rng=rng, cur=first, pos=L, max_new=max_new,
+                          emitted=[first], t_admit=t_admit)
+            if len(state.emitted) >= max_new:
+                self._finish(state, now)      # one-token request: never occupies
+                done.append(req)
+                return
+            self._caches = self._insert(self._caches, cache, jnp.asarray(slot, jnp.int32))
+            self._slots[slot] = state
 
     def _finish(self, state: _Slot, now: int) -> None:
         req = state.req
@@ -357,9 +377,6 @@ class ServingEngine:
         done: List[Request] = []
         now = 0
         B = self.ecfg.max_batch
-        col = _obs_collector()
-        t_serve0 = time.perf_counter()
-        tok0 = self.stats["tokens_out"]
 
         def active() -> int:
             return sum(s is not None for s in self._slots)
@@ -374,48 +391,40 @@ class ServingEngine:
                 self._admit(pending.pop(0), i, now, done)
                 if self._slots[i] is None:   # finished at admission: reusable
                     free.append(i)
-            if not active():
+            n_act = active()
+            if not n_act:
                 continue
 
-            tokens = np.zeros((B, 1), np.int32)
-            pos = np.zeros((B,), np.int32)
-            for i, s in enumerate(self._slots):
-                if s is not None:
-                    tokens[i, 0] = s.cur
-                    pos[i] = s.pos
-            logits, self._caches = self._run_decode(
-                jnp.asarray(tokens), jnp.asarray(pos)
-            )
-            n_act = active()
-            self.stats["decode_steps"] += 1
-            self.stats["slot_steps_active"] += n_act
-            self.stats["slot_steps_idle"] += B - n_act
-            # Per-tick gauges go through the sampler: ticks are the engine's
-            # highest-frequency site, and the last-written value is what a
-            # gauge means anyway.
-            if col.enabled and col.sample():
-                col.gauge("serve.queue_depth", len(pending))
-                col.gauge("serve.slots_active", n_act)
-            now += 1
-            logits_np = np.asarray(logits, np.float32)
-            for i, s in enumerate(self._slots):
-                if s is None:
-                    continue
-                nxt = _sample_one(logits_np[i], s.req, s.rng)
-                s.emitted.append(nxt)
-                s.pos += 1
-                s.cur = nxt
-                if len(s.emitted) >= s.max_new:
-                    self._finish(s, now)
-                    done.append(s.req)
-                    self._slots[i] = None     # freed: next arrival admits here
-        if col.enabled:
-            wall = time.perf_counter() - t_serve0
-            if wall > 0:
-                col.gauge(
-                    "serve.tokens_per_s",
-                    (self.stats["tokens_out"] - tok0) / wall,
-                )
+            with _obs_span("serve.tick", tick=self.stats["decode_steps"],
+                           active=n_act, queued=len(pending)):
+                with _obs_span("serve.decode"):
+                    tokens = np.zeros((B, 1), np.int32)
+                    pos = np.zeros((B,), np.int32)
+                    for i, s in enumerate(self._slots):
+                        if s is not None:
+                            tokens[i, 0] = s.cur
+                            pos[i] = s.pos
+                    logits, self._caches = self._run_decode(
+                        jnp.asarray(tokens), jnp.asarray(pos)
+                    )
+                self.stats["decode_steps"] += 1
+                self.stats["slot_steps_active"] += n_act
+                self.stats["slot_steps_idle"] += B - n_act
+                now += 1
+                with _obs_span("serve.fetch"):
+                    logits_np = np.asarray(logits, np.float32)
+                with _obs_span("serve.sample"):
+                    for i, s in enumerate(self._slots):
+                        if s is None:
+                            continue
+                        nxt = _sample_one(logits_np[i], s.req, s.rng)
+                        s.emitted.append(nxt)
+                        s.pos += 1
+                        s.cur = nxt
+                        if len(s.emitted) >= s.max_new:
+                            self._finish(s, now)
+                            done.append(s.req)
+                            self._slots[i] = None     # freed: next arrival admits here
         return sorted(done, key=lambda r: r._order)
 
     # ---------------------------------------------------------------- warmup

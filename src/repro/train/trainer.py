@@ -270,6 +270,8 @@ class Trainer:
             lambda x, s: jax.device_put(x, s), batch_np, self._b_sh
         )
         t0 = time.perf_counter()
+        # The span closes after the metrics are read back, so it times the
+        # step itself, not its enqueue.
         with _obs_span("train.step", step=self.step):
             with self._scope():
                 self.params, self.opt_state, self.ef_state, metrics = (
@@ -277,7 +279,7 @@ class Trainer:
                         self.params, self.opt_state, self.ef_state, batch
                     )
                 )
-        metrics = {k: float(v) for k, v in metrics.items()}
+            metrics = {k: float(v) for k, v in metrics.items()}
         dt = time.perf_counter() - t0
         self.monitor.record(self.step, dt)
         self.step += 1
@@ -289,7 +291,6 @@ class Trainer:
                 int(np.prod(leaves[0].shape[:2]))
                 if leaves and getattr(leaves[0], "ndim", 0) >= 2 else 0
             )
-            col.observe("train.step_s", dt)
             if tokens and dt > 0:
                 col.counter("train.tokens", tokens)
                 col.gauge("train.tokens_per_s", tokens / dt)

@@ -523,7 +523,7 @@ def test_serving_engine_records_latency_histograms():
         done = engine.serve()
     assert len(done) == 3
     snap = col.snapshot()
-    adm = percentile_row(snap, "serve.admission_s")
+    adm = percentile_row(snap, "span.serve.admit")
     tok = percentile_row(snap, "serve.per_token_s")
     lat = percentile_row(snap, "serve.latency_s")
     assert adm["count"] == 3 and lat["count"] == 3 and tok["count"] == 3
