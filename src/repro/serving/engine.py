@@ -63,8 +63,18 @@ top level:
                     it), ``active``, ``queued``; its children:
       serve.decode  — building the token/position arrays and enqueueing the
                       decode step;
-      serve.fetch   — the logits to the host (waits for the decode step);
+      serve.fetch   — the step's picked token ids to the host (waits for the
+                      decode step); the logits too only when an active slot
+                      samples (``temperature > 0``);
       serve.sample  — per-slot sampling and retirement.
+
+Greedy picks are made on the device: the prefill and decode jits return
+``argmax(logits, -1)`` beside the logits (the first index of the maximum,
+as ``np.argmax`` picks it), so a tick of greedy slots copies ``max_batch``
+ids, not the pool's logits. Every served token still comes out of
+:func:`_sample_one`; a greedy slot hands it a :class:`_Picked` row, whose
+argmax the device already took. ``stats["device_pick_ticks"]`` counts the
+ticks that copied ids only.
 """
 from __future__ import annotations
 
@@ -120,9 +130,34 @@ class EngineConfig:
     #                                 queueing — backpressure, not OOM
 
 
-def _sample_one(logits_row: np.ndarray, req: Request, rng) -> int:
+def _with_ids(out):
+    """(logits, caches) -> (logits, caches, ids): ``ids`` [B] int32 is the
+    greedy pick of each row, made on the device."""
+    logits, caches = out
+    return logits, caches, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+class _Picked:
+    """A row of logits as far as a greedy pick reads it: the index of its
+    maximum, which the device already took, and its length."""
+
+    __slots__ = ("index", "size")
+
+    def __init__(self, index: int, size: int):
+        self.index, self.size = index, size
+
+    def argmax(self) -> int:
+        return self.index
+
+    def __len__(self) -> int:
+        return self.size
+
+
+def _sample_one(logits_row, req: Request, rng) -> int:
+    """The next token of one request from its row of logits (a host array,
+    or a :class:`_Picked` row for a greedy request)."""
     if req.temperature <= 0:
-        return int(np.argmax(logits_row))
+        return int(logits_row.argmax())
     z = logits_row / req.temperature
     z = z - z.max()
     p = np.exp(z)
@@ -174,12 +209,12 @@ class ServingEngine:
             spec.mixer != "attn" for seg in cfg.segments() for spec in seg.pattern
         )
         self._prefill = jax.jit(
-            lambda p, toks, L: lm.prefill(
+            lambda p, toks, L: _with_ids(lm.prefill(
                 p, {"tokens": toks}, cfg, run, cache_len=ecfg.max_seq, true_len=L
-            )
+            ))
         )
         self._decode = jax.jit(
-            lambda p, t, c, pos: lm.decode_step(p, t, c, pos, cfg, run)
+            lambda p, t, c, pos: _with_ids(lm.decode_step(p, t, c, pos, cfg, run))
         )
         self._insert = jax.jit(lm.insert_cache)
         self._caches = lm.init_cache(cfg, ecfg.max_batch, ecfg.max_seq)
@@ -202,6 +237,7 @@ class ServingEngine:
     def reset_stats(self) -> None:
         self.stats: Dict[str, int] = {
             "decode_steps": 0,        # pool decode invocations (= ticks)
+            "device_pick_ticks": 0,   # ticks that copied the picked ids only
             "prefill_calls": 0,
             "prefill_tokens": 0,      # padded (bucketed) prefill tokens
             "slot_steps_active": 0,   # slot·steps that produced a kept token
@@ -258,9 +294,9 @@ class ServingEngine:
         if self._prefill_ref is None:
             cfg, run, ecfg = self.cfg, self.run, self.ecfg
             self._prefill_ref = jax.jit(
-                lambda p, t, n: lm.prefill(
+                lambda p, t, n: _with_ids(lm.prefill(
                     p, {"tokens": t}, cfg, run, cache_len=ecfg.max_seq, true_len=n
-                )
+                ))
             )
         with self._scope(), self._ref_scope():
             return self._prefill_ref(self.params, toks, L)
@@ -276,7 +312,7 @@ class ServingEngine:
         if self._decode_ref is None:
             cfg, run = self.cfg, self.run
             self._decode_ref = jax.jit(
-                lambda p, t, c, q: lm.decode_step(p, t, c, q, cfg, run)
+                lambda p, t, c, q: _with_ids(lm.decode_step(p, t, c, q, cfg, run))
             )
         # self._caches is only reassigned from a call that RETURNED, so the
         # retry reruns the identical inputs — completed requests stay
@@ -328,7 +364,7 @@ class ServingEngine:
                        prompt_len=L, bucket=sb):
             toks = np.zeros((1, sb), np.int32)
             toks[0, :L] = req.prompt
-            logits, cache = self._run_prefill(
+            logits, cache, ids = self._run_prefill(
                 jnp.asarray(toks), jnp.asarray(L, jnp.int32)
             )
             self.stats["prefill_calls"] += 1
@@ -339,7 +375,11 @@ class ServingEngine:
             req.slot = slot
             t_admit = self.clock()
             rng = np.random.default_rng(req.seed)
-            first = _sample_one(np.asarray(logits, np.float32)[0], req, rng)
+            if req.temperature <= 0:
+                row = _Picked(int(np.asarray(ids)[0]), logits.shape[-1])
+            else:
+                row = np.asarray(logits, np.float32)[0]
+            first = _sample_one(row, req, rng)
             req.first_token_s = self.clock()
             col = _obs_collector()
             if col.enabled:
@@ -404,20 +444,28 @@ class ServingEngine:
                         if s is not None:
                             tokens[i, 0] = s.cur
                             pos[i] = s.pos
-                    logits, self._caches = self._run_decode(
+                    logits, self._caches, ids = self._run_decode(
                         jnp.asarray(tokens), jnp.asarray(pos)
                     )
                 self.stats["decode_steps"] += 1
                 self.stats["slot_steps_active"] += n_act
                 self.stats["slot_steps_idle"] += B - n_act
                 now += 1
+                sampling = any(s is not None and s.req.temperature > 0
+                               for s in self._slots)
                 with _obs_span("serve.fetch"):
-                    logits_np = np.asarray(logits, np.float32)
+                    ids_np = np.asarray(ids)
+                    if sampling:
+                        logits_np = np.asarray(logits, np.float32)
+                if not sampling:
+                    self.stats["device_pick_ticks"] += 1
                 with _obs_span("serve.sample"):
                     for i, s in enumerate(self._slots):
                         if s is None:
                             continue
-                        nxt = _sample_one(logits_np[i], s.req, s.rng)
+                        row = (logits_np[i] if s.req.temperature > 0
+                               else _Picked(int(ids_np[i]), logits.shape[-1]))
+                        nxt = _sample_one(row, s.req, s.rng)
                         s.emitted.append(nxt)
                         s.pos += 1
                         s.cur = nxt

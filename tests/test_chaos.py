@@ -150,6 +150,34 @@ def test_unguarded_fault_degrades_engine_not_requests(served_ref):
     assert not eng.degraded
 
 
+def test_decode_fault_degrades_onto_reference_jit_that_picks_on_device(served_ref):
+    """A fault escaping the decode step alone (its prefill bucket compiled
+    before the plan) moves every tick onto the reference decode jit, which
+    picks greedy tokens on the device as the kernel jit does."""
+    cfg, params, ref_out = served_ref
+    rt = TunedRuntime(
+        db=TuningDatabase(None), mode="kernel", guard=False,
+        name="chaos-decode",
+    )
+    eng = ServingEngine(
+        cfg, RUN, params, make_host_mesh(), Layout(),
+        EngineConfig(max_batch=3, max_seq=MAX_SEQ), runtime=rt,
+    )
+    assert {eng._bucket_len(n) for n, _, _ in SCHEDULE} == {16}
+    eng.submit(Request(prompt=_prompt(cfg, 3, 0), max_new_tokens=1))
+    eng.serve()                                   # prefill compiled, no decode
+    eng.reset_stats()
+    plan = FaultPlan([FaultRule(site="dispatch.kernel:*")], name="decode-fault")
+    with plan:
+        out = _serve_schedule(cfg, eng)
+    for got, want in zip(out, ref_out):
+        np.testing.assert_array_equal(got, want)
+    assert plan.fired and eng.degraded
+    st = eng.stats
+    assert st["degraded_calls"] == st["decode_steps"] > 0
+    assert st["device_pick_ticks"] == st["decode_steps"]
+
+
 def test_submit_sheds_with_structured_response_at_max_queue(served_ref):
     cfg, params, _ = served_ref
     eng = ServingEngine(

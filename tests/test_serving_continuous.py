@@ -240,3 +240,73 @@ def test_ssm_arch_exact_length_prefill_matches_solo():
             )
             ref.append(int(jnp.argmax(logits[0])))
         np.testing.assert_array_equal(r.output, np.asarray(ref, np.int32))
+
+
+# --------------------------------------------------------------------------
+# Greedy picks made on the device
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_device_pick_equals_host_pick_with_planted_ties(dtype):
+    """The jits' argmax picks what the host's widened-float32 argmax picks,
+    the first index of the maximum on a tie included."""
+    from repro.serving.engine import _sample_one, _with_ids
+
+    rs = np.random.RandomState(3)
+    x = rs.randn(6, 1000).astype(np.float32)
+    for row, (a, b) in enumerate([(5, 900), (0, 1), (998, 999), (17, 400),
+                                  (250, 251), (3, 600)]):
+        x[row, [a, b]] = x[row].max() + 1.0          # a tie at the maximum
+    logits = jnp.asarray(x, dtype)
+    _, _, ids = jax.jit(_with_ids)((logits, None))
+    host = np.asarray(logits, np.float32)
+    assert ids.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(ids), np.argmax(host, -1))
+    np.testing.assert_array_equal(np.asarray(ids), [5, 0, 998, 17, 250, 3])
+    greedy = Request(prompt=np.zeros(1, np.int32))
+    assert [_sample_one(r, greedy, None) for r in host] == list(np.asarray(ids))
+
+
+def test_greedy_pool_copies_ids_only_and_matches_solo(served):
+    cfg, params, eng = served
+    eng.reset_stats()
+    _check_schedule(cfg, params, eng, [(0, 17, 9, 0), (0, 3, 4, 1), (0, 12, 7, 2),
+                                       (2, 9, 6, 3), (1, 12, 5, 1)])
+    assert eng.stats["decode_steps"] > 0
+    assert eng.stats["device_pick_ticks"] == eng.stats["decode_steps"]
+
+
+def test_mixed_pool_copies_logits_only_on_sampling_ticks(served):
+    """Greedy and temperature > 0 requests in one pool: each gets the output
+    it gets alone, and only the ticks with no sampling slot copy ids only."""
+    cfg, params, eng = served
+    mk = lambda length, n, temp, seed, arrival: Request(
+        prompt=_prompt(cfg, length, seed), max_new_tokens=n, temperature=temp,
+        seed=seed, arrival_time=arrival)
+    spec = [(17, 10, 0.0, 0, 0.0), (9, 4, 1.0, 5, 0.0), (12, 8, 0.0, 2, 1.0),
+            (3, 5, 0.7, 9, 6.0), (9, 3, 0.0, 1, 7.0)]
+    solo = {}
+    for s in spec:
+        if s[2] > 0:
+            eng.reset_stats()
+            eng.submit(mk(*s[:4], 0.0))
+            (r,) = eng.serve()
+            assert eng.stats["device_pick_ticks"] == 0
+            solo[s] = r.output
+    eng.reset_stats()
+    reqs = [mk(*s) for s in spec]
+    for r in reqs:
+        eng.submit(r)
+    done = eng.serve()
+    sampling_ticks = set()
+    for s, r in zip(spec, done):
+        if r.temperature > 0:
+            np.testing.assert_array_equal(r.output, solo[s])
+            sampling_ticks.update(range(r.admitted_step, r.finished_step))
+        else:
+            np.testing.assert_array_equal(
+                r.output, _solo_greedy(cfg, params, r.prompt, r.max_new_tokens))
+    st = eng.stats
+    assert 0 < st["device_pick_ticks"] < st["decode_steps"]
+    assert st["device_pick_ticks"] == st["decode_steps"] - len(sampling_ticks)
