@@ -8,6 +8,7 @@ test them.
 """
 from __future__ import annotations
 
+import functools
 import glob
 import os
 import re
@@ -18,6 +19,10 @@ WINDOW = "bench.window"
 # Device ops whose interval holds other ops: counted in the busy union, left
 # out of the per-op ranking (their time is their children's).
 _CONTAINERS = {"while", "conditional", "call"}
+# HLO opcodes of the collectives; each may also run as an async start/done pair.
+COLLECTIVES = ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
+               "collective-permute")
+_COLLECTIVE_OPCODES = {c + s for c in COLLECTIVES for s in ("", "-start", "-done")}
 
 
 def load(profile_dir):
@@ -78,13 +83,29 @@ def busy_s(rec, t0, t1):
     return sum(per) / len(per) if per else 0.0
 
 
+@functools.lru_cache(maxsize=None)
+def _op(text):
+    """(name without numeric suffix, opcode, operand types, result types,
+    signature or None) of a device op's text; a step's programs repeat the
+    same few thousand texts, so each is parsed once."""
+    name, opcode, ins, outs = parse_op(text)
+    sig = signature(ins, outs) if is_kernel_call(text) else None
+    return re.sub(r"(\.\d+)+$", "", name), opcode, ins, outs, sig
+
+
 def op_label(text, kernels):
     """(label, opcode) of a device op: the label is the Pallas kernel it
     runs, else its HLO instruction name without the numeric suffix."""
-    name, opcode, ins, outs = parse_op(text)
-    if is_kernel_call(text):
-        return kernels.get(signature(ins, outs), ("tpu_custom_call",))[0], opcode
-    return re.sub(r"(\.\d+)+$", "", name), opcode
+    name, opcode, _, _, sig = _op(text)
+    if sig is not None:
+        return kernels.get(sig, ("tpu_custom_call",))[0], opcode
+    return name, opcode
+
+
+@functools.lru_cache(maxsize=None)
+def is_collective(text):
+    """Whether a device op, by its HLO opcode, is a collective."""
+    return re.sub(r"(\.\d+)+$", "", _op(text)[1]) in _COLLECTIVE_OPCODES
 
 
 def top_ops(rec, t0, t1, kernels, n=10):
@@ -136,8 +157,8 @@ def kernel_calls(rec, t0, t1, kernels):
     out = []
     for ops in rec["devices"].values():
         for text, a, b in _clip(ops, t0, t1):
-            if is_kernel_call(text):
-                _, _, ins, outs = parse_op(text)
-                kernel, true_ins = kernels.get(signature(ins, outs), (None, ins))
+            _, _, ins, outs, sig = _op(text)
+            if sig is not None:
+                kernel, true_ins = kernels.get(sig, (None, ins))
                 out.append((kernel, true_ins, outs, (b - a) * 1e-9))
     return out

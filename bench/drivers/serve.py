@@ -1,7 +1,8 @@
 """Serving cells: ``ServingEngine.submit`` and ``ServingEngine.serve`` on an
-engine built as ``repro.launch.serve.build`` builds one, with the cell's
-slot pool and cache, the tuning database the cell names (none in these
-cells), and the benchmark's clock.
+engine built as ``repro.launch.serve.build`` builds one, on the cell's mesh,
+with the cell's slot pool and cache, the tuning database the cell names (none
+in these cells), and the benchmark's clock. The configuration's model family
+checks the program's model and gives the reference.
 
 Set-up makes the weights from the seed (one jitted call, as the launcher
 does) and serves one short request per prefill bucket the cell's traffic
@@ -28,7 +29,6 @@ import time
 import numpy as np
 
 import program
-import reference as R
 import traffic
 
 
@@ -51,12 +51,12 @@ def build(run, seed):
     smoke = prog.get("smoke", False)
     if smoke:
         cfg = cfg.reduced()
-    mesh = make_mesh_from_spec("1x1")
+    mesh = make_mesh_from_spec(run.cell["mesh"])
     layout = defaults.default_layout(cfg)
     rn = defaults.default_run(cfg, SHAPES["decode_32k"])
     if smoke:
         rn = dataclasses.replace(rn, q_chunk=32, k_chunk=max(32, e["max_seq"]), loss_chunk=32)
-    program.check_model(cfg, run.cfg)
+    run.family.check_model(cfg, run.cfg)
     params = jax.jit(lambda k: lm.init_params(k, cfg)[0])(jax.random.PRNGKey(seed))
     rt = repro.runtime(db=program.tuning_db(run), mode=e["mode"], name="bench-serve")
     engine = ServingEngine(cfg, rn, params, mesh, layout,
@@ -107,10 +107,11 @@ def sample(done, tokens, seed):
     return picked
 
 
-def gaps(cfg, params, reqs, quant=None):
-    """Per request, the gap of each served token under the reference; with
-    ``quant``, the gap (under the reference) of the token a ``quant``
-    reference puts first at each of those positions."""
+def gaps(run, params, reqs, quant=None):
+    """Per request, the gap of each served token under the family's
+    reference; with ``quant``, the gap (under the reference) of the token a
+    ``quant`` reference puts first at each of those positions."""
+    R, cfg = run.family, run.cfg
     out = []
     for r in reqs:
         L, o = len(r.prompt), np.asarray(r.output)
@@ -159,10 +160,10 @@ def run(run):
     run.read_memory_peak()
     free(engine)
 
-    params = R.make_params(run.cfg, run.seed)
+    params = run.family.make_params(run.cfg, run.seed)
     picked = sample(done, run.cell["check"]["tokens"], run.seed)
     with jax.default_matmul_precision("highest"):
-        widest = max(float(g.max()) for g in gaps(run.cfg, params, picked))
+        widest = max(float(g.max()) for g in gaps(run, params, picked))
     del params
     return {
         "metrics": {"serve_tokens_per_s": out_tokens / elapsed,
@@ -171,6 +172,7 @@ def run(run):
         "attempted": len(done), "failed": failed,
         "setup_compile_s": compile_s,
         "notes": [f"dispatch tiers at trace time: {program.tiers(rt)}",
+                  f"mesh: {dict(engine.mesh.shape)}",
                   f"warm prefill buckets: {buckets}",
                   f"window: {w} waves, {len(done)} requests, {out_tokens} output tokens in "
                   f"{elapsed!r} s (waves {[round(x, 3) for x in wave_times]}); engine {st}",

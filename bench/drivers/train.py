@@ -1,6 +1,8 @@
 """Training cells: ``Trainer.run_one_step`` as ``repro.launch.train.build``
-builds it, on the cell's batch, with the tuning database the cell names
-(none in these cells).
+builds it, on the cell's mesh (``--mesh``) and global batch (``--batch``: the
+rows of a step over all chips), with the tuning database the cell names (none
+in these cells). The configuration's model family checks the program's model
+and gives the reference.
 
 Set-up builds one trainer from the seed, feeds it the seed's batches
 (``traffic.TrainFeed``) and drives its first ``warm_steps`` steps through the
@@ -29,7 +31,6 @@ import tempfile
 import time
 
 import program
-import reference as R
 import traffic
 
 KEEP_FRACTION = 1e-3
@@ -42,7 +43,7 @@ def build(run, seed):
 
     cfg, t = run.cfg, run.cell["train"]
     args = train.parser().parse_args([
-        "--arch", cfg["program"]["arch"], "--mesh", "1x1",
+        "--arch", cfg["program"]["arch"], "--mesh", run.cell["mesh"],
         "--batch", str(t["batch"]), "--seq-len", str(t["seq_len"]),
         "--steps", str(run.cell["optimizer"]["total_steps"]), "--mode", t["mode"],
         "--seed", str(seed), "--ckpt-dir", tempfile.mkdtemp(prefix="bench-ckpt-"),
@@ -50,38 +51,38 @@ def build(run, seed):
     ] + (["--smoke"] if cfg["program"].get("smoke") else []))
     rt = repro.runtime(db=program.tuning_db(run), mode=t["mode"], name="bench-train")
     trainer, _ = train.build(args, runtime=rt)
-    _check_matches(trainer, cfg, run.cell)
+    _check_matches(trainer, run)
     trainer.data = traffic.TrainFeed(seed, t["batch"], t["seq_len"], cfg["vocab_size"])
     return trainer
 
 
-def _check_matches(trainer, cfg, cell):
+def _check_matches(trainer, run):
     """The program runs what the configuration and the cell state."""
-    program.check_model(trainer.cfg, cfg)
-    r, o, t = trainer.run, trainer.opt_cfg, cell["train"]
+    run.family.check_model(trainer.cfg, run.cfg)
+    r, o, t = trainer.run, trainer.opt_cfg, run.cell["train"]
     bad = {k: (getattr(r, k), t[k]) for k in ("microbatches", "remat", "loss_chunk")
            if getattr(r, k) != t[k]}
-    bad.update({"optimizer." + k: (getattr(o, k), v) for k, v in cell["optimizer"].items()
+    bad.update({"optimizer." + k: (getattr(o, k), v) for k, v in run.cell["optimizer"].items()
                 if getattr(o, k) != v})
     if bad:
         raise RuntimeError(f"the program departs from the cell (program, file): {bad}")
 
 
-def warm(trainer, steps):
-    """Run the first ``steps`` steps; returns the program's readings. The
-    update is the change of the float32 master weights from their own start
-    (on a TPU the master starts from the unrounded draw, not from the
-    bfloat16 weights); that start waits on the host, so the readings take no
-    device memory the steps need."""
+def warm(run, trainer):
+    """Run the cell's first ``warm_steps`` steps; returns the program's
+    readings. The update is the change of the float32 master weights from
+    their own start (on a TPU the master starts from the unrounded draw, not
+    from the bfloat16 weights); that start waits on the host, so the readings
+    take no device memory the steps need."""
     import jax
     import jax.numpy as jnp
 
     p0 = jax.device_get(trainer.opt_state["master"])
     out = {"loss": []}
-    for i in range(1, steps + 1):
+    for i in range(1, run.cell["train"]["warm_steps"] + 1):
         out["loss"].append(trainer.run_one_step()["loss"])
         if i == 1:
-            out["m1"] = R.leaf_norms(trainer.opt_state["m"])
+            out["m1"] = run.family.leaf_norms(trainer.opt_state["m"])
             out["m1_host"] = jax.device_get(trainer.opt_state["m"])
     diff = jax.jit(lambda a, b: jnp.linalg.norm(a - b))
     paths, leaves = zip(*jax.tree_util.tree_flatten_with_path(trainer.opt_state["master"])[0])
@@ -133,7 +134,7 @@ def run(run):
     with run.phase("build"):
         trainer = build(run, run.seed)
     with run.phase("warm_steps"):
-        prog = warm(trainer, t["warm_steps"])
+        prog = warm(run, trainer)
     compile_s = run.compiles.seconds
     steps = 0
     with run.window():
@@ -146,6 +147,7 @@ def run(run):
     tokens = steps * t["batch"] * t["seq_len"]
     run.counters.update(rows=steps * t["batch"], seq_len=t["seq_len"])
     tier = program.tiers(trainer.runtime)
+    mesh = dict(trainer.mesh.shape)
     failed = steps if tier.get("reference") else 0
     if run.trace:
         import roofline
@@ -161,8 +163,8 @@ def run(run):
     del trainer
     gc.collect()
 
-    ref = R.train_readings(run.cfg, run.seed, reference_batches(run, run.seed, t["warm_steps"]),
-                           run.cell["optimizer"])
+    ref = run.family.train_readings(
+        run.cfg, run.seed, reference_batches(run, run.seed, t["warm_steps"]), run.cell["optimizer"])
     gaps, worst = compare(prog, ref)
     limits = run.cell["limits"]
     return {
@@ -170,6 +172,7 @@ def run(run):
         "attempted": steps, "failed": failed,
         "setup_compile_s": compile_s,
         "notes": [f"dispatch tiers at trace time: {tier}",
+                  f"mesh: {mesh}",
                   f"window: {steps} steps of {t['batch']} x {t['seq_len']} tokens in {elapsed!r} s",
                   f"warm losses: program {prog['loss']}, reference {ref['loss']}",
                   f"worst leaves (leaf, program norm, reference norm): {worst}",

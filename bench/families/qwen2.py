@@ -1,10 +1,22 @@
-"""Plain Qwen2 reference, written from the published description.
+"""The Qwen2 family: everything the harness needs of a dense Qwen2 block.
 
-Decoder-only transformer: token embedding; per layer RMSNorm -> grouped-query
-attention with biased q/k/v projections and rotary positions (rotate-half,
-theta from the config) -> residual -> RMSNorm -> SwiGLU MLP -> residual;
-final RMSNorm; an untied output head. Everything is computed in float32 at
-``highest`` matmul precision. Nothing of the system under test is imported.
+A configuration file names its family (``"family": "qwen2"``) and the harness
+loads ``bench/families/<family>.py``; a new model family is a new file here
+with the same names, and no edit to the drivers, readers or calibration:
+
+- ``check_model(arch_cfg, cfg)``: raise unless the program's model is the
+  configuration file's;
+- ``matmul_params``, ``train_flops``, ``serve_flops``: the model operations
+  of the work a cell completes, which ``step_mfu.*`` reads;
+- the plain reference that decides ``correct``: ``make_params``,
+  ``logits_at``, ``train_readings``, ``leaf_norms``, ``control_quant``.
+
+The reference is a decoder-only transformer written from the published
+description: token embedding; per layer RMSNorm -> grouped-query attention
+with biased q/k/v projections and rotary positions (rotate-half, theta from
+the config) -> residual -> RMSNorm -> SwiGLU MLP -> residual; final RMSNorm;
+an untied output head. Everything is computed in float32 at ``highest``
+matmul precision. Nothing of the system under test is imported.
 
 The weights are drawn from the seed by ``init_params``: normal draws scaled
 by 1/sqrt(fan-in), zero biases, unit norm scales, stored in the dtype the
@@ -40,6 +52,72 @@ def dims(cfg):
         theta=float(cfg["rope_theta"]), eps=float(cfg["rms_norm_eps"]),
         dtype=jnp.dtype(cfg["torch_dtype"]),
     )
+
+
+# ---------------------------------------------------------------------------
+# the program's model and its operations
+# ---------------------------------------------------------------------------
+
+
+def check_model(c, cfg):
+    """Raise unless the program's ArchConfig ``c`` is the configuration file
+    ``cfg``'s dense Qwen2 block at its sizes."""
+    got = {
+        "num_hidden_layers": c.num_layers, "hidden_size": c.d_model,
+        "num_attention_heads": c.num_heads, "num_key_value_heads": c.num_kv_heads,
+        "head_dim": c.hd, "intermediate_size": c.d_ff, "vocab_size": c.vocab_size,
+        "rope_theta": c.rope_theta, "rms_norm_eps": c.norm_eps, "torch_dtype": c.dtype,
+        "qkv_bias": c.qkv_bias,
+    }
+    bad = {k: (v, cfg[k]) for k, v in got.items() if v != cfg[k]}
+    if c.ffn_kind != "swiglu" or c.num_experts or c.window or any(
+            spec.mixer != "attn" for seg in c.segments() for spec in seg.pattern):
+        bad["block"] = (c.ffn_kind, c.num_experts, c.window)
+    if bad:
+        raise RuntimeError(f"the program's model departs from the configuration "
+                           f"(program, file): {bad}")
+
+
+# Multiply-add = 2. Matmul parameters are the weights of every projection and
+# of the output head (the embedding is a gather). Attention counts q.k and p.v
+# over the causal pairs. Training counts the forward and backward passes (3x
+# the forward) and nothing recomputed.
+
+
+def matmul_params(cfg):
+    d, H, KV, hd = (cfg["hidden_size"], cfg["num_attention_heads"],
+                    cfg["num_key_value_heads"], cfg["head_dim"])
+    layer = d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * cfg["intermediate_size"]
+    return cfg["num_hidden_layers"] * layer + d * cfg["vocab_size"]
+
+
+def _attn_pairs_flops(cfg, pairs):
+    """q.k and p.v over ``pairs`` (query, key) pairs, in every layer."""
+    return 4.0 * cfg["num_attention_heads"] * cfg["head_dim"] * pairs * cfg["num_hidden_layers"]
+
+
+def train_flops(cfg, rows, seq_len):
+    """Forward and backward of ``rows`` causal sequences of ``seq_len``."""
+    tokens = rows * seq_len
+    fwd = 2.0 * matmul_params(cfg) * tokens
+    fwd += _attn_pairs_flops(cfg, rows * seq_len * (seq_len + 1) / 2)
+    return 3.0 * fwd
+
+
+def serve_flops(cfg, requests):
+    """Prefill of each true prompt, then one decode step per further output
+    token, for [(prompt length, output tokens)]."""
+    head = cfg["hidden_size"] * cfg["vocab_size"]
+    layers = matmul_params(cfg) - head
+    total = 0.0
+    for L, n in requests:
+        # the layers see the prompt and then each output token but the last
+        # (decode steps at positions L .. L + n - 2); the head runs once per
+        # output token
+        total += 2.0 * layers * (L + n - 1) + 2.0 * head * n
+        pairs = L * (L + 1) / 2 + sum(L + 1 + j for j in range(n - 1))
+        total += _attn_pairs_flops(cfg, pairs)
+    return total
 
 
 # ---------------------------------------------------------------------------
@@ -252,8 +330,10 @@ def row_loss_sum(params, tokens, labels, m, quant=None):
 
 
 def batch_grad(params, tokens, labels, m, quant=None):
-    """(mean loss, mean gradient) over all tokens of a batch, one row at a
-    time so the activations of one row are live at once."""
+    """(mean loss, mean gradient) over all tokens of a batch laid out as
+    [groups, rows, seq]: each group (one device's share of the rows) a row
+    at a time, so the activations of one row a device are live at once; the
+    groups' sums are then added."""
     grad_row = jax.value_and_grad(row_loss_sum)
     zeros = jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
 
@@ -262,9 +342,12 @@ def batch_grad(params, tokens, labels, m, quant=None):
         l, gr = grad_row(params, row[0], row[1], m, quant)
         return (tot + l, jax.tree_util.tree_map(lambda a, b: a + b.astype(jnp.float32), g, gr)), None
 
-    (tot, g), _ = jax.lax.scan(body, (jnp.zeros((), jnp.float32), zeros), (tokens, labels))
-    n = tokens.shape[0] * tokens.shape[1]
-    return tot / n, jax.tree_util.tree_map(lambda x: x / n, g)
+    def group(toks, labs):
+        return jax.lax.scan(body, (jnp.zeros((), jnp.float32), zeros), (toks, labs))[0]
+
+    tot, g = jax.vmap(group)(tokens, labels)
+    n = math.prod(tokens.shape)
+    return tot.sum(0) / n, jax.tree_util.tree_map(lambda x: x.sum(0) / n, g)
 
 
 def adamw_lr(opt, step):
@@ -302,16 +385,30 @@ def leaf_norms(tree):
     return dict(zip(paths, (float(n) for n in norms)))
 
 
+def _row_mesh(rows):
+    """A mesh over the most local devices that divide ``rows``: the reference
+    spreads a batch's rows over them and holds its state on each."""
+    devices = jax.local_devices()
+    n = max(k for k in range(1, len(devices) + 1) if rows % k == 0)
+    return jax.sharding.Mesh(np.array(devices[:n]), ("rows",))
+
+
 def train_readings(cfg, seed, batches, opt, quant=None, rows=None):
     """Run the reference's first len(batches) AdamW steps from the seed's
     weights: {"loss": [per step], "m1": {leaf: norm of the first moment after
     step 1}, "m1_host": that first moment on the host, "update": {leaf: norm
-    of the master's change after the last step}}. ``rows`` keeps only the first rows of each batch (a planted
-    fault: half the batch left out)."""
+    of the master's change after the last step}}. ``rows`` keeps only the
+    first rows of each batch (a planted fault: half the batch left out). The
+    rows of a batch are spread over the local devices (``_row_mesh``); a
+    device holds the whole state, as on one."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
     m = dims(cfg)
     m_items = tuple(sorted(m.items(), key=lambda kv: kv[0]))
+    mesh = _row_mesh(rows or len(batches[0][0]))
+    whole, split = NamedSharding(mesh, PartitionSpec()), NamedSharding(mesh, PartitionSpec("rows"))
     f32 = lambda t: jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), t)
-    master = f32(make_params(cfg, seed))
+    master = jax.device_put(f32(make_params(cfg, seed)), whole)
     state = {
         "master": master,
         "m": jax.tree_util.tree_map(jnp.zeros_like, master),
@@ -319,13 +416,14 @@ def train_readings(cfg, seed, batches, opt, quant=None, rows=None):
     }
     del master
     hyper = (opt["b1"], opt["b2"], opt["eps"], opt["weight_decay"], opt["grad_clip"])
+    groups = lambda x: jax.device_put(x.reshape(mesh.size, -1, x.shape[-1]), split)
     out = {"loss": []}
     with jax.default_matmul_precision("highest"):
         for i, (toks, labels) in enumerate(batches, start=1):
             if rows:
                 toks, labels = toks[:rows], labels[:rows]
             state, loss = _train_step(
-                state, jnp.asarray(toks), jnp.asarray(labels),
+                state, groups(toks), groups(labels),
                 adamw_lr(opt, i), 1 - opt["b1"] ** i, 1 - opt["b2"] ** i,
                 hyper, m_items, quant)
             out["loss"].append(float(loss))
@@ -335,5 +433,6 @@ def train_readings(cfg, seed, batches, opt, quant=None, rows=None):
         master = state["master"]
         del state
         out["update"] = leaf_norms(jax.tree_util.tree_map(
-            lambda a, b: a - b.astype(jnp.float32), master, make_params(cfg, seed)))
+            lambda a, b: a - b.astype(jnp.float32), master,
+            jax.device_put(make_params(cfg, seed), whole)))
     return out

@@ -6,7 +6,6 @@ UNIT = "%"
 BETTER = "lower"
 SOURCE = "device_trace"
 MOVES = "train_tokens_per_s"
-WORKLOADS = ["qwen2-0.5b.train_8x1k"]
 
 
 def read(run):

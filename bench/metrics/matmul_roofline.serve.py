@@ -7,7 +7,6 @@ UNIT = "%"
 BETTER = "higher"
 SOURCE = "device_trace"
 MOVES = "serve_tokens_per_s"
-WORKLOADS = ["qwen2.5-3b.prefill_heavy", "qwen2.5-3b.decode_heavy"]
 
 
 def read(run):

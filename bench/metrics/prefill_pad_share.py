@@ -7,7 +7,6 @@ UNIT = "%"
 BETTER = "lower"
 SOURCE = "program_counter"
 MOVES = "serve_tokens_per_s"
-WORKLOADS = ["qwen2.5-3b.prefill_heavy"]
 
 
 def read(run):

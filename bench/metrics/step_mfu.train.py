@@ -8,7 +8,6 @@ UNIT = "%"
 BETTER = "higher"
 SOURCE = "device_trace"
 MOVES = "train_tokens_per_s"
-WORKLOADS = ["qwen2-0.5b.train_8x1k"]
 
 
 def read(run):

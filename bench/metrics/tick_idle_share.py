@@ -8,7 +8,6 @@ UNIT = "%"
 BETTER = "lower"
 SOURCE = "program_span"
 MOVES = "serve_tokens_per_s"
-WORKLOADS = ["qwen2.5-3b.prefill_heavy", "qwen2.5-3b.decode_heavy"]
 
 
 def read(run):

@@ -5,7 +5,6 @@ from __future__ import annotations
 import sys
 
 import devtrace
-import flops
 import roofline
 
 
@@ -32,11 +31,27 @@ def mfu(run, model_flops):
 
 
 def train_mfu(run):
-    return mfu(run, flops.train_flops(run.cfg, run.counters["rows"], run.counters["seq_len"]))
+    return mfu(run, run.family.train_flops(run.cfg, run.counters["rows"], run.counters["seq_len"]))
 
 
 def serve_mfu(run):
-    return mfu(run, flops.serve_flops(run.cfg, run.counters["requests"]))
+    return mfu(run, run.family.serve_flops(run.cfg, run.counters["requests"]))
+
+
+def collective_share(run):
+    """Percent of the traced window in which a collective op (all-reduce,
+    reduce-scatter, all-gather, all-to-all, collective-permute, their async
+    start and done included, by HLO opcode) ran on the first device; None
+    where no device was traced or no collective ran in the window."""
+    t0, t1 = run.span
+    if not run.records["devices"]:
+        return None
+    ops = next(iter(run.records["devices"].values()))
+    coll = [op for op in ops if devtrace.is_collective(op[0])]
+    busy = devtrace.busy_intervals(coll, t0, t1)
+    if not busy:
+        return None
+    return 100.0 * sum(b - a for a, b in busy) * 1e-9 / _window_s(run)
 
 
 def kernel_roofline(run, family):
@@ -53,9 +68,9 @@ def kernel_roofline(run, family):
         if kernel is None:
             unknown += 1
             continue
-        if roofline.FAMILY.get(kernel) != family:
+        if roofline.family(kernel, run.bench) != family:
             continue
-        t, bound = roofline.least_time(kernel, ins, outs, run.peak)
+        t, bound = roofline.least_time(kernel, ins, outs, run.peak, run.bench)
         least += t
         spent += secs
         by_bound[bound] += t

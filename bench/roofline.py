@@ -4,28 +4,26 @@ Multiply-add = 2 operations. Bytes are what the call must read and write at
 least: every operand once and every result once. Least time is the larger
 of operations over the chip's peak rate and bytes over its memory
 bandwidth; which of the two is larger says what bounds the call. Adapted
-from the system's own per-site roofline model, per Pallas call, with no
-fallback: a kernel this table does not know raises.
+from the system's own per-site roofline model, per Pallas call.
+
+Each Pallas kernel (the function a pallas_call runs) that a roofline metric
+reads has a file of its own, ``bench/kernels/<kernel>.py``, found by the
+kernel's name: ``FAMILY``, the family whose metric reads it
+(``<family>_roofline.*``), and ``work(ins, outs)``, its (operations, bytes)
+from the operand and result types. A kernel with no file belongs to no
+metric, and asking for its work raises: nothing falls back.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import re
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# Pallas kernel (the function a pallas_call runs) -> the family a roofline
-# metric reads. A kernel missing here belongs to no metric.
-FAMILY = {
-    "_matmul_kernel": "matmul",
-    "_flash_kernel": "flash_attention",
-    "_flash_bwd_dq_kernel": "flash_attention",
-    "_flash_bwd_dkv_kernel": "flash_attention",
-}
-
-_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "u8": 1,
-          "f8e4m3fn": 1, "pred": 1, "s64": 8, "f64": 8}
+BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "u8": 1,
+         "f8e4m3fn": 1, "pred": 1, "s64": 8, "f64": 8}
 _JAX_TO_HLO = {"bfloat16": "bf16", "float16": "f16", "float32": "f32",
                "int32": "s32", "uint32": "u32", "int8": "s8", "uint8": "u8",
                "bool": "pred", "int64": "s64", "float64": "f64",
@@ -49,38 +47,57 @@ def _n(shape):
     return out
 
 
-def _nbytes(types):
-    return sum(_n(s) * _BYTES[dt] for dt, s in types)
+def nbytes(types):
+    """Bytes of a list of (dtype, shape)."""
+    return sum(_n(s) * BYTES[dt] for dt, s in types)
 
 
-def _attended_pairs(sq, sk):
+def attended_pairs(sq, sk):
     """(query, key) pairs a causal call attends: queries sit at the end of
     the key axis."""
     off = sk - sq
     return sq * off + sq * (sq + 1) // 2
 
 
-def work(kernel, ins, outs):
-    """(operations, bytes) of one call of ``kernel`` with operand types
+@functools.lru_cache(maxsize=None)
+def kernel(name, bench=HERE):
+    """The module of kernel ``name`` (``bench/kernels/<name>.py``), or None
+    where the kernel has no file; looked up once a run (a trace holds
+    hundreds of thousands of calls)."""
+    import importlib.util
+
+    path = os.path.join(bench, "kernels", name + ".py")
+    if not os.path.isfile(path):
+        return None
+    spec = importlib.util.spec_from_file_location("bench_kernel_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(name, bench=HERE):
+    """The family whose roofline metric reads kernel ``name``; None for a
+    kernel with no file."""
+    mod = kernel(name, bench)
+    return mod.FAMILY if mod else None
+
+
+def work(name, ins, outs, bench=HERE):
+    """(operations, bytes) of one call of kernel ``name`` with operand types
     ``ins`` and result types ``outs``, each a list of (dtype, shape). The
     operand types are those before any padding to the kernel's tiles: the
     work the call has to do, not the zeros it also multiplies."""
-    if kernel == "_matmul_kernel":
-        (dt, (m, k)), (_, (_, n)) = ins[0], ins[1]
-        return 2.0 * m * k * n, (m * k + k * n + m * n) * _BYTES[dt]
-    nbytes = _nbytes(ins) + _nbytes(outs)
-    if kernel in ("_flash_kernel", "_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
-        (_, (bh, sq, d)), (_, (_, sk, _)) = ins[0], ins[1]
-        # forward: q.k and p.v; backward dq pass: do.v and ds.k; dkv pass:
-        # p.do and ds.q -- two products of 2*d operations per pair each
-        return 4.0 * bh * d * _attended_pairs(sq, sk), nbytes
-    raise KeyError(f"no operation count for kernel {kernel!r}")
+    mod = kernel(name, bench)
+    if mod is None:
+        raise KeyError(f"no operation count for kernel {name!r}: no file "
+                       f"{os.path.join(bench, 'kernels', name + '.py')}")
+    return mod.work(ins, outs)
 
 
-def least_time(kernel, ins, outs, peak):
+def least_time(name, ins, outs, peak, bench=HERE):
     """(seconds, bound) of one call: bound is "compute" or "memory"."""
-    ops, nbytes = work(kernel, ins, outs)
-    t_c, t_m = ops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"]
+    ops, nb = work(name, ins, outs, bench)
+    t_c, t_m = ops / peak["bf16_flops"], nb / peak["hbm_bytes_per_s"]
     return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
 
 
@@ -142,7 +159,7 @@ def _close(text, i):
 
 def _types(text):
     return [(dt, tuple(int(x) for x in dims.split(",") if x))
-            for dt, dims in _TYPE.findall(text) if dt in _BYTES]
+            for dt, dims in _TYPE.findall(text) if dt in BYTES]
 
 
 def parse_op(text):

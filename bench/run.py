@@ -1,23 +1,34 @@
 #!/usr/bin/env python3
-"""One measured run of one benchmark cell, on the chip it is started on.
+"""One measured run of one benchmark cell, on the chips it is started on.
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Everything a cell is made of is found by name: its file
-``bench/workloads/<cell>.json`` names a configuration
-(``bench/configs/<config>.json``), a driver (``bench/drivers/<driver>.py``),
-its chips and its traffic; ``BENCHMARK.json`` lists the metrics each cell
-reports, and each per-layer metric is read by ``bench/metrics/<metric>.py``.
+Everything a cell is made of is found by name, so a later cell, model family,
+metric or kernel is added as files and list entries, with no edit here:
+
+- the cell's file ``bench/workloads/<cell>.json`` names its configuration
+  (``bench/configs/<config>.json``), its driver (``bench/drivers/<driver>.py``),
+  its chips, its mesh (``"mesh": "DATAxMODEL"``, as the launchers' ``--mesh``
+  reads it, over as many devices as the cell's chips) and its traffic;
+- the configuration names its model family, ``bench/families/<family>.py``:
+  the check that the program's model is the file's, the model operations of
+  ``step_mfu.*`` and the plain reference that decides ``correct``;
+- ``BENCHMARK.json`` lists the metrics each cell reports (a metric's
+  ``workloads`` is the only list of its cells), and each per-layer metric is
+  read by ``bench/metrics/<metric>.py``;
+- a Pallas kernel's roofline (its family and its operations and bytes) is
+  ``bench/kernels/<kernel>.py`` (``roofline.py``).
 
 A run checks for the chips the cell needs (none found: exit 3, no result),
 builds the system under test from the seed, warms up every shape the window
-uses (set-up), measures for ``--seconds``, reads the device's peak memory,
-frees the system and then checks what the window produced against the plain
-reference (``bench/reference.py``). With ``--trace 1`` the window runs under
-the profiler and the per-layer metrics are read from the trace. Standard
-error carries the set-up split, the dispatch tiers, compilations in the
-window and, last, each number compared beside its limit; the last line of
-standard output is the result as one JSON object.
+uses (set-up), measures for ``--seconds`` (a traced run for at most the
+cell's ``trace_seconds``, where it states one), reads the device's peak memory,
+frees the system and then checks what the window produced against the family's
+plain reference. With ``--trace 1`` the window runs under the profiler and the
+per-layer metrics are read from the trace. Standard error carries the set-up
+split, the dispatch tiers, compilations in the window and, last, each number
+compared beside its limit; the last line of standard output is the result as
+one JSON object.
 """
 from __future__ import annotations
 
@@ -27,6 +38,7 @@ T_START = time.perf_counter()
 
 import argparse
 import contextlib
+import functools
 import importlib.util
 import json
 import math
@@ -56,6 +68,12 @@ def load_module(path, name):
     return mod
 
 
+@functools.lru_cache(maxsize=None)
+def load_family(bench, name):
+    """The model family module ``bench/families/<name>.py`` (loaded once)."""
+    return load_module(os.path.join(bench, "families", name + ".py"), "bench_family_" + name)
+
+
 def applies(metric, cell):
     return "workloads" not in metric or cell in metric["workloads"]
 
@@ -83,18 +101,20 @@ class CompileClock:
 
 
 class Run:
-    """What a driver gets from the harness: the cell, its configuration, the
-    seed, the window's length and whether it is traced; and the clocks and
-    records it reports through."""
+    """What a driver gets from the harness: the cell, its configuration and
+    model family, the seed, the window's length and whether it is traced; and
+    the clocks and records it reports through."""
 
     def __init__(self, bench, name, cell, cfg, seed, seconds, trace, peak):
         self.bench, self.name, self.cell, self.cfg = bench, name, cell, cfg
+        self.family = load_family(bench, cfg["family"])
         self.seed, self.seconds, self.trace = seed, seconds, trace
         self.peak = peak
         self.compiles = CompileClock()
         self.setup_split = {}
         self.setup_s = None
         self.in_window = None
+        self.window_end = None
         self.memory_peak_bytes = None
         self.counters = {}       # what the per-layer readers count with
         self.kernels = {}        # Pallas call signature -> kernel name
@@ -127,15 +147,20 @@ class Run:
                 stack.enter_context(obs.collect(name="bench", xla_annotations=True))
             stack.enter_context(jax.profiler.TraceAnnotation("bench.window"))
             yield
+            self.window_end = time.perf_counter()
         self.in_window = (self.compiles.seconds - c0, self.compiles.backend_compiles - n0)
         if prof_dir:
             import devtrace as tr
 
+            t_stop = time.perf_counter()
             try:
                 self.records = tr.load(prof_dir)
                 self.span = tr.window(self.records)
             finally:
                 shutil.rmtree(prof_dir, ignore_errors=True)
+            log(f"trace: stopped in {t_stop - self.window_end!r} s, loaded in "
+                f"{time.perf_counter() - t_stop!r} s, "
+                f"{sum(len(v) for v in self.records['devices'].values())} device ops")
 
     def read_memory_peak(self):
         """The peak bytes in use on the fullest chip; read before the
@@ -193,7 +218,11 @@ def main(argv=None, bench=BENCH, program_root=None, chip_check=True, compile_cac
     import roofline
 
     peak = roofline.peaks(devices[0].device_kind) if chip_check else None
-    run = Run(bench, args.workload, cell, cfg, args.seed, args.seconds, bool(args.trace), peak)
+    # A traced window may be cut to the cell's ``trace_seconds``: the profiler
+    # takes ~30 us an op to collect the trace, so a cell with many chips
+    # could not stop, read and check a whole window within a run's time.
+    seconds = min(args.seconds, cell.get("trace_seconds", args.seconds)) if args.trace else args.seconds
+    run = Run(bench, args.workload, cell, cfg, args.seed, seconds, bool(args.trace), peak)
     driver = load_module(os.path.join(bench, "drivers", cell["driver"] + ".py"),
                          f"bench_driver_{cell['driver']}")
     res = driver.run(run)
@@ -204,6 +233,8 @@ def main(argv=None, bench=BENCH, program_root=None, chip_check=True, compile_cac
         f"{run.in_window[0]} s of compile events")
     for line in res.get("notes", []):
         log(line)
+    log(f"after the window (stopping and loading the trace, the reference): "
+        f"{time.perf_counter() - run.window_end!r} s")
 
     want = [m for m in spec["end_to_end"] if applies(m, args.workload)]
     metrics = {m["name"]: {"value": res["metrics"][m["name"]], "unit": m["unit"]} for m in want}
@@ -214,10 +245,12 @@ def main(argv=None, bench=BENCH, program_root=None, chip_check=True, compile_cac
         import devtrace as tr
 
         t0, t1 = run.span
+        t_read = time.perf_counter()
         out["metrics"] = read_per_layer(run, spec, bench)
         out["breakdown"] = {"device_ops": tr.top_ops(run.records, t0, t1, run.kernels),
                             "idle_gaps": tr.idle_gaps(run.records, t0, t1)}
         device["busy_s"], device["window_s"] = tr.busy_s(run.records, t0, t1), (t1 - t0) * 1e-9
+        log(f"trace: read in {time.perf_counter() - t_read!r} s")
     else:
         out["metrics"] = metrics
     out["device"] = device

@@ -1,12 +1,18 @@
 """The benchmark's operation and byte counts against hand counts at
-Qwen2-0.5B's shapes, and the reading of device-op names."""
+Qwen2-0.5B's shapes, the kernel roofline files found by the kernel's name, and
+the reading of device-op names."""
 from __future__ import annotations
+
+import os
+import types
 
 import pytest
 
-import bench_smoke  # noqa: F401  (puts the benchmark on the path)
-import flops
+import bench_smoke as B
 import roofline
+import run as harness
+
+flops = harness.load_family(B.BENCH, "qwen2")
 
 QWEN2_05B = {"num_hidden_layers": 24, "hidden_size": 896, "num_attention_heads": 14,
              "num_key_value_heads": 2, "head_dim": 64, "intermediate_size": 4864,
@@ -47,6 +53,55 @@ def test_flash_attention_counts_causal_pairs():
 def test_unknown_kernel_raises():
     with pytest.raises(KeyError):
         roofline.work("_mystery_kernel", [("bf16", (8, 8))], [])
+    assert roofline.family("_mystery_kernel") is None
+    assert roofline.family("_rmsnorm_kernel") is None      # runs, but no metric reads it
+
+
+def test_every_kernel_file_names_its_family():
+    kernels = sorted(f[:-3] for f in os.listdir(os.path.join(B.BENCH, "kernels"))
+                     if f.endswith(".py"))
+    for k in kernels:
+        mod = roofline.kernel(k)
+        assert isinstance(mod.FAMILY, str) and callable(mod.work), k
+    assert {k: roofline.family(k) for k in (
+        "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel", "_flash_kernel", "_matmul_kernel")} == {
+        "_flash_bwd_dkv_kernel": "flash_attention", "_flash_bwd_dq_kernel": "flash_attention",
+        "_flash_kernel": "flash_attention", "_matmul_kernel": "matmul"}
+    # the backward passes count as the forward does
+    q, kv = ("bf16", (28, 1024, 64)), ("bf16", (4, 1024, 64))
+    fwd = roofline.work("_flash_kernel", [q, kv, kv], [q])
+    assert roofline.work("_flash_bwd_dq_kernel", [q, kv, kv], [q]) == fwd
+    assert roofline.work("_flash_bwd_dkv_kernel", [q, kv, kv], [q]) == fwd
+
+
+def test_a_kernel_added_as_a_file_is_found_and_read(tmp_path):
+    """A kernel's roofline added as one file to a copy of the benchmark joins
+    its family's metric; a kernel with no file still raises."""
+    bench = B.make_copy(tmp_path)
+    with open(os.path.join(bench, "kernels", "_scan_kernel.py"), "w") as f:
+        f.write('"""A toy scan: one add per element, read and written once."""\n'
+                "from roofline import nbytes\n\nFAMILY = 'scan'\n\n\n"
+                "def work(ins, outs):\n"
+                "    (_, (n, d)), = ins\n"
+                "    return float(n * d), nbytes(ins) + nbytes(outs)\n")
+    ins, outs = [("f32", (1024, 256))], [("f32", (1024, 256))]
+    assert roofline.family("_scan_kernel", bench) == "scan"
+    assert roofline.family("_scan_kernel") is None          # not in the benchmark itself
+    assert roofline.work("_scan_kernel", ins, outs, bench) == (1024 * 256, 2 * 4 * 1024 * 256)
+    with pytest.raises(KeyError):
+        roofline.work("_mystery_kernel", ins, outs, bench)
+    # the scan family's roofline over a hand trace: one call of 4 ms
+    sig = roofline.signature(ins, outs)
+    text = ("%closed_call.3 = f32[1024,256]{1,0} custom-call(f32[1024,256]{1,0} %x), "
+            'custom_call_target="tpu_custom_call"')
+    run = types.SimpleNamespace(
+        bench=bench, peak=PEAK, span=(0, 10 * 10**6), kernels={sig: ("_scan_kernel", ins)},
+        records={"devices": {"/device:TPU:0": [[text, 10**6, 4 * 10**6]]}, "host": []})
+    import readers
+
+    least = 2 * 4 * 1024 * 256 / PEAK["hbm_bytes_per_s"]
+    assert readers.kernel_roofline(run, "scan") == pytest.approx(100 * least / 4e-3)
+    assert readers.kernel_roofline(run, "matmul") is None
 
 
 def test_train_flops_by_hand():

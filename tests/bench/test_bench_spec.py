@@ -1,10 +1,11 @@
 """BENCHMARK.json against the files that make the benchmark: every cell,
-configuration and per-layer metric is found by its name, and the file keeps
-to the shape the benchmark's contract asks for."""
+configuration, model family and per-layer metric is found by its name, and the
+file keeps to the shape the benchmark's contract asks for."""
 from __future__ import annotations
 
 import importlib.util
 import json
+import math
 import os
 import re
 
@@ -48,6 +49,10 @@ def test_configuration_file(cfg):
         data = json.load(f)
     assert data["name"] == cfg["name"] and data["source"] == cfg["source"]
     assert any(w["config"] == cfg["name"] for w in SPEC["workloads"])
+    family = _module(os.path.join(B.BENCH, "families", data["family"] + ".py"))
+    for name in ("check_model", "matmul_params", "train_flops", "serve_flops", "make_params",
+                 "logits_at", "train_readings", "leaf_norms", "control_quant"):
+        assert callable(getattr(family, name)), name
     for k in cfg["reduced"]:
         assert not re.search(r"(size|_dim|_rank|heads|experts_per_tok)$", k), k
 
@@ -61,6 +66,9 @@ def test_cell_file(cell):
         data = json.load(f)
     assert data["config"] == cell["config"] and data["chips"] == cell["chips"]
     assert os.path.exists(os.path.join(B.BENCH, "drivers", data["driver"] + ".py"))
+    # the mesh the drivers build spans the chips the cell asks for
+    assert re.fullmatch(r"\d+x\d+", data["mesh"])
+    assert math.prod(int(d) for d in data["mesh"].split("x")) == cell["chips"]
     e2e = [m["name"] for m in SPEC["end_to_end"]
            if "workloads" not in m or cell["name"] in m["workloads"]]
     assert "setup_s" in e2e and len(e2e) >= 2
@@ -78,15 +86,19 @@ def test_end_to_end_metric(metric):
 @pytest.mark.parametrize("metric", SPEC["per_layer"], ids=lambda m: m["name"])
 def test_per_layer_metric_reader(metric):
     """Each per-layer metric has a reader file of its own that declares what
-    BENCHMARK.json says of it, and moves a metric its cells report."""
+    BENCHMARK.json says of it, and every cell that reports it reports the
+    end-to-end metric it moves. The cells are BENCHMARK.json's list alone: a
+    reader names none."""
     assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
     mod = _module(os.path.join(B.BENCH, "metrics", metric["name"] + ".py"))
-    assert (mod.LAYER, mod.UNIT, mod.BETTER, mod.SOURCE, mod.MOVES, mod.WORKLOADS) == (
-        metric["layer"], metric["unit"], metric["better"], metric["source"],
-        metric["moves"], metric["workloads"])
+    assert (mod.LAYER, mod.UNIT, mod.BETTER, mod.SOURCE, mod.MOVES) == (
+        metric["layer"], metric["unit"], metric["better"], metric["source"], metric["moves"])
+    assert not hasattr(mod, "WORKLOADS")
     assert callable(mod.read)
     moves = next(m for m in SPEC["end_to_end"] if m["name"] == metric["moves"])
-    assert set(metric["workloads"]) <= set(moves.get("workloads", metric["workloads"]))
+    cells = metric.get("workloads", [w["name"] for w in SPEC["workloads"]])
+    assert cells and set(cells) <= {w["name"] for w in SPEC["workloads"]}
+    assert set(cells) <= set(moves.get("workloads", cells))
     if metric["name"].endswith("_roofline") or "_roofline." in metric["name"] or "mfu" in metric["name"]:
         assert metric["unit"] == "%"
 

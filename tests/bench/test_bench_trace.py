@@ -91,3 +91,44 @@ def test_recorded_trace(recorded):
     assert 0 < share <= 1.05
     top = devtrace.top_ops(recorded, t0, t1, kernels)
     assert top[0][0] == "_matmul_kernel" and len(top) <= 10
+
+
+def _collective_reader():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "m_collective_share", os.path.join(bench_smoke.BENCH, "metrics", "collective_share.train.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def test_collective_share_by_hand():
+    """Window 0..10 ms on the first device: an async all-reduce (start 1..2,
+    done 2..3 ms), an all-to-all 5..6 ms overlapping a fusion 5..7 ms, a
+    reduce-scatter 9..11 ms cut at the window's end; a matmul and the fusion
+    are not collectives. A second device's collectives are not read."""
+    import types
+
+    dev0 = [["%all-reduce-start.1 = (bf16[896]{0}, bf16[896]{0}) all-reduce-start(bf16[896]{0} %g), "
+             "channel_id=1, replica_groups=[1,4]<=[4]", 1 * MS, 1 * MS],
+            ["%all-reduce-done.1 = bf16[896]{0} all-reduce-done((bf16[896]{0}, bf16[896]{0}) "
+             "%all-reduce-start.1)", 2 * MS, 1 * MS],
+            [MM.format(n="closed_call.1"), 3 * MS, 2 * MS],
+            ["%all-to-all.4 = (s32[1,2,64]{2,1,0}) all-to-all(s32[1,2,64]{2,1,0} %t)", 5 * MS, 1 * MS],
+            ["%fusion.3 = bf16[8]{0} fusion(bf16[8]{0} %x), kind=kLoop", 5 * MS, 2 * MS],
+            ["%reduce-scatter.2 = f32[224]{0} reduce-scatter(f32[896]{0} %g), dimensions={0}",
+             9 * MS, 2 * MS]]
+    dev1 = [["%all-gather.2 = f32[896]{0} all-gather(f32[224]{0} %g), dimensions={0}", 0, 10 * MS]]
+    read = _collective_reader()
+    run = types.SimpleNamespace(
+        span=(0, 10 * MS),
+        records={"devices": {"/device:TPU:0": dev0, "/device:TPU:1": dev1}, "host": []})
+    # 1..3 + 5..6 + 9..10 ms of 10
+    assert read(run) == pytest.approx(40.0)
+    assert [devtrace.is_collective(op[0]) for op in dev0] == [True, True, False, True, False, True]
+    # no collective in the window, or no device traced: nothing to read
+    run.records["devices"] = {"/device:TPU:0": dev0[2:3] + dev0[4:5]}
+    assert read(run) is None
+    run.records["devices"] = {}
+    assert read(run) is None
